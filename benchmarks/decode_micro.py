@@ -82,8 +82,8 @@ def _build_pool(rng, b, n_ctx, hkv, d, ps, kv_dtype="fp32"):
     from repro.serving import paged_cache as PC
     pg_dtype = (jnp.float32 if kv_dtype == "fp32"
                 else Q.payload_dtype(kv_dtype))
-    cache = {"pages_k": jnp.zeros((num_pages, ps, hkv, d), pg_dtype),
-             "pages_v": jnp.zeros((num_pages, ps, hkv, d), pg_dtype),
+    cache = {"pages_k": jnp.zeros((num_pages, hkv, ps, d), pg_dtype),
+             "pages_v": jnp.zeros((num_pages, hkv, ps, d), pg_dtype),
              "centroids": jnp.zeros((num_pages, hkv, d), jnp.float32)}
     if kv_dtype != "fp32":
         cache["scales_k"] = jnp.ones((num_pages, hkv), jnp.float32)

@@ -233,7 +233,7 @@ def moba_paged_decode_attention(q: jax.Array, pages_k: jax.Array,
     per kv head, never touching the rest of the pool.
 
     q:           (B, H, 1, d)
-    pages_k/v:   (P, page_size, Hkv, d) shared pool (one layer slot)
+    pages_k/v:   (P, Hkv, page_size, d) shared pool (one layer slot)
     centroids:   (P, Hkv, d) fp32 per-page centroid cache
     block_table: (B, npg) int32 physical page ids, -1 = unassigned
     kv_len:      (B,) int32 valid lengths *including* the token appended
@@ -243,7 +243,7 @@ def moba_paged_decode_attention(q: jax.Array, pages_k: jax.Array,
                  them — centroids are fp32 regardless of pool dtype.
     """
     b, h, _, d = q.shape
-    _, ps, hkv, _ = pages_k.shape
+    _, hkv, ps, _ = pages_k.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
 
@@ -253,24 +253,14 @@ def moba_paged_decode_attention(q: jax.Array, pages_k: jax.Array,
     qg = _group_queries(q, hkv).astype(jnp.float32)          # (B,Hkv,G,1,d)
     tbl = jnp.maximum(block_table, 0)
     phys = tbl[jnp.arange(b)[:, None, None, None, None], idx]
+    hsel = jnp.arange(hkv)[None, :, None, None, None]
 
     # gather only the selected pages, per kv head: (B,Hkv,G,1,k,ps,d)
-    pk_t = pages_k.transpose(2, 0, 1, 3)                     # (Hkv,P,ps,d)
-    pv_t = pages_v.transpose(2, 0, 1, 3)
-
-    def per_head(pool_h, idx_h):                             # (P,ps,d)
-        return pool_h[idx_h]                                 # (B,G,1,k,ps,d)
-
-    kg = jax.vmap(per_head, in_axes=(0, 1), out_axes=1)(
-        pk_t, phys)
-    vg = jax.vmap(per_head, in_axes=(0, 1), out_axes=1)(
-        pv_t, phys)
-    kg = kg.astype(jnp.float32)
-    vg = vg.astype(jnp.float32)
+    kg = pages_k[phys, hsel].astype(jnp.float32)
+    vg = pages_v[phys, hsel].astype(jnp.float32)
     if scales_k is not None:
         # mirror the page gather on the (P, Hkv) scale leaves: one
         # scalar per selected (page, kv head), broadcast over (ps, d)
-        hsel = jnp.arange(hkv)[None, :, None, None, None]
         kg = kg * scales_k[phys, hsel][..., None, None]
         vg = vg * scales_v[phys, hsel][..., None, None]
     s = jnp.einsum("bhgqd,bhgqkld->bhgqkl", qg, kg) * scale
@@ -351,7 +341,7 @@ def moba_paged_prefill_attention(q: jax.Array, pages_k: jax.Array,
     fresh-prefill path cannot do.  Padded query rows (beyond ``q_len``)
     select nothing and output zeros.
 
-    q: (B, H, L, d); pages_k/v: (P, ps, Hkv, d); centroids: (P, Hkv, d);
+    q: (B, H, L, d); pages_k/v: (P, Hkv, ps, d); centroids: (P, Hkv, d);
     block_table: (B, npg); kv_len: (B,) pre-chunk lengths (the chunk and
     its centroid updates must already be appended); q_len: (B,);
     scales_k/v: (P, Hkv) fp32 per-page dequant scales of a quantized
@@ -359,7 +349,7 @@ def moba_paged_prefill_attention(q: jax.Array, pages_k: jax.Array,
     the routing centroids.
     """
     b, h, nq, d = q.shape
-    _, ps, hkv, _ = pages_k.shape
+    _, hkv, ps, _ = pages_k.shape
     npg = block_table.shape[1]
     if scale is None:
         scale = 1.0 / (d ** 0.5)
@@ -379,10 +369,10 @@ def moba_paged_prefill_attention(q: jax.Array, pages_k: jax.Array,
     tbl = jnp.maximum(block_table, 0)
 
     def densify(pool, scales):
-        g = pool[tbl].astype(jnp.float32)                    # (B,npg,ps,h,d)
+        g = pool[tbl].astype(jnp.float32)                    # (B,npg,h,ps,d)
         if scales is not None:
-            g = g * scales[tbl][:, :, None, :, None]
-        return g.transpose(0, 3, 1, 2, 4).reshape(b, hkv, npg * ps, d)
+            g = g * scales[tbl][..., None, None]
+        return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, npg * ps, d)
 
     kf = densify(pages_k, scales_k)
     vf = densify(pages_v, scales_v)

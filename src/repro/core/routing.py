@@ -126,9 +126,10 @@ class VarlenLayout(NamedTuple):
     preserved inside a block), then pad each block's run to a multiple of
     the physical tile Tq so every tile maps to exactly one key block.
 
-    All shapes are static: capacity L = Nq*k + nb*Tq upper-bounds any
-    padding outcome (each of nb blocks wastes < Tq slots; sentinel pairs
-    are parked in the trailing region).
+    All shapes are static: capacity L = roundup(Nq*k, Tq) + nb*Tq
+    upper-bounds any padding outcome (each of nb blocks wastes < Tq
+    slots; sentinel pairs are parked in the trailing region) and is a
+    whole number of tiles even when Nq*k is not.
     """
 
     q_index: jax.Array      # (L,) int32: query position per slot, -1 = pad
@@ -161,7 +162,7 @@ def build_varlen_layout(top_idx: jax.Array, nq: int, nb: int,
     offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                jnp.cumsum(counts[:-1]).astype(jnp.int32)])
 
-    capacity = nq * k + nb * tile
+    capacity = layout_capacity(nq, k, nb, tile)
     rank = jnp.arange(sb.shape[0], dtype=jnp.int32) - offsets[sb]
     slot = starts[sb] + rank                               # (Nq*k,)
 
@@ -181,4 +182,4 @@ def build_varlen_layout(top_idx: jax.Array, nq: int, nb: int,
 
 
 def layout_capacity(nq: int, k: int, nb: int, tile: int) -> int:
-    return nq * k + nb * tile
+    return -(-nq * k // tile) * tile + nb * tile

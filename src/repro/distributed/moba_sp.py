@@ -27,7 +27,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import MoBAConfig
 from repro.core import routing
@@ -80,12 +79,12 @@ def moba_attention_sp(q: jax.Array, k: jax.Array, v: jax.Array,
             q_l, k_l, v_l, cfg, q_positions=qpos, scale=scale,
             tile=min(tile, nq_local), use_scan=use_scan)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec, None, axis, None),
                   P(bspec, None, None, None),
                   P(bspec, None, None, None)),
-        out_specs=P(bspec, None, axis, None), check_rep=False)
+        out_specs=P(bspec, None, axis, None), check_vma=False)
     return fn(q, k, v)
 
 
@@ -221,22 +220,22 @@ def moba_decode_cp(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     cent_spec = (P(bspec, None, axis, None) if centroids is not None
                  else P())
     if centroids is None:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda q_l, k_l, v_l, kl: local_fn(q_l, k_l, v_l, kl, None),
             mesh=mesh,
             in_specs=(P(bspec, None, None, None),
                       P(bspec, None, axis, None),
                       P(bspec, None, axis, None),
                       P()),
-            out_specs=P(bspec, None, None, None), check_rep=False)
+            out_specs=P(bspec, None, None, None), check_vma=False)
         return fn(q, k_cache, v_cache,
                   kv_len.reshape(1).astype(jnp.int32))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(bspec, None, None, None),
                   P(bspec, None, axis, None),
                   P(bspec, None, axis, None),
                   P(), cent_spec),
-        out_specs=P(bspec, None, None, None), check_rep=False)
+        out_specs=P(bspec, None, None, None), check_vma=False)
     return fn(q, k_cache, v_cache, kv_len.reshape(1).astype(jnp.int32),
               centroids)

@@ -64,11 +64,10 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x, mesh: Mesh,
         return jax.lax.psum(jnp.where(stage == s_count - 1, outs, 0.0),
                             axis)
 
-    from jax.experimental.shard_map import shard_map
     spec_p = P(axis)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(jax.tree.map(lambda _: spec_p, params_stacked),
-                             P()),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(jax.tree.map(lambda _: spec_p,
+                                              params_stacked), P()),
+                       out_specs=P(), check_vma=False)
     outs = fn(params_stacked, mb)
     return outs.reshape(b, *x.shape[1:])

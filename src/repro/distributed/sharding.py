@@ -28,9 +28,7 @@ def use_mesh(mesh: Mesh, cfg: ShardingConfig):
     prev = dict(_ACTIVE)
     _ACTIVE.update(mesh=mesh, cfg=cfg)
     try:
-        use = (jax.sharding.use_mesh(mesh)
-               if hasattr(jax.sharding, "use_mesh") else mesh)
-        with use:
+        with mesh:
             yield
     finally:
         _ACTIVE.update(prev)
@@ -153,18 +151,12 @@ def param_specs(params, mesh: Mesh, cfg: ShardingConfig):
     return jax.tree_util.tree_unflatten(flat[1], specs)
 
 
-def make_compat_mesh(shape, axes) -> Mesh:
-    """Construct a mesh across JAX versions.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg)
-    only exist in newer JAX; older installs get the implicit-auto mesh,
-    which has identical semantics for our use (everything is Auto)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+def auto_mesh(shape, axes) -> Mesh:
+    """A mesh over the local devices whose axes are all ``Auto``: the
+    compiler propagates shardings from the annotations above."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(cfg: MeshConfig) -> Mesh:
-    return make_compat_mesh(cfg.shape, cfg.axes)
+    return auto_mesh(cfg.shape, cfg.axes)

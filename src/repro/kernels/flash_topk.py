@@ -4,27 +4,33 @@ Streams tiles of Q against tiles of the centroid matrix K̃ and maintains a
 running per-query top-k (scores, block ids) in VMEM scratch — the full
 (Nq × nb) score matrix never exists in HBM.
 
-Two grids (DESIGN.md §2):
+Two grids (DESIGN.md §2), one kernel:
 
 * ``grouped`` (default, MXU-shaped): grid (B·Hkv, Nq/Tq, ct) — the q
   block covers all G query heads of a GQA group (heads are contiguous
   per kv head in the (B·H) layout), so ONE centroid-tile DMA serves the
   whole group (1/G of the flat grid's centroid traffic) and the score
-  matmul is a single (G·Tq, d) · (d, C) MXU product.  The running
-  top-k is maintained by a **two-stage merge**: a tile-local top-k of
-  the C candidate lanes via a bitonic tournament (sort kp-lane groups,
-  then fold halves keeping each pair's top kp — O(log(C/kp)·log kp)
-  compare-exchange stages), then one (k ∪ k) bitonic merge against the
-  running list — replacing the flat grid's O(k·(k+C)) per-tile k-pass
-  extraction.  The merge lists are padded to ``kp`` lanes (power of
-  two, at least the sublane grain).
-* ``flat`` (legacy, kept selectable for bisection): grid
-  (B·H, Nq/Tq, ct), per-query-head centroid DMAs, and the original
-  k-pass masked max-extraction (one VPU-wide max + compare per pass
-  with a cumsum tie-break).
+  matmul is a single (G·Tq, d) · (d, C) MXU product.
+* ``flat`` (kept selectable for bisection): grid (B·H, Nq/Tq, ct) — the
+  same kernel with G = 1 and a per-query-head centroid DMA.
 
-Both grids break score ties toward the lower block id — exactly
-``jax.lax.top_k``'s order — so results are bit-identical to the oracle.
+The running top-k is maintained by a **two-stage bitonic merge** over
+the C candidate lanes: a tile-local tournament (sort kp-lane groups in
+alternating directions, then fold pairs of groups with one
+compare-exchange plus a kp-wide merge — O(log(C/kp)·log kp) stages)
+leaves the tile's top kp in lanes [0, kp), and one half-cleaner against
+the running list (kept ascending in lanes [kp, 2kp)) merges it.  Merge
+lists are padded to ``kp`` lanes (power of two, at least the sublane
+grain), and the lane width is at least 2·kp.
+
+Every compare-exchange pairs lane ``l`` with lane ``l ^ j`` through two
+``pltpu.roll`` lane rotations and lane-iota masks — no lane reversal
+and no lane-splitting reshape, neither of which Mosaic lowers.  The
+masks are derived by rotating the lane iota itself, so they hold for
+either rotation direction.
+
+Ties break toward the lower block id — exactly ``jax.lax.top_k``'s
+order — so results are bit-identical to the oracle.
 
 Selection semantics (must match `repro.core.routing.select_blocks`):
   * future blocks masked to −inf
@@ -41,167 +47,105 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
-from repro.kernels.tiling import SUBLANE, check_topk_tiling, next_pow2
+from repro.kernels.tiling import SUBLANE, check_blocks, next_pow2
 
 NEG_INF = -1e30       # mask level (matches core.routing)
-EXTRACTED = -2e30     # strictly below mask level: never re-picked as valid
-INIT = -3e30
+INIT = -3e30          # empty running-list slot: below every mask level
 POS_INF = 1e30
 
 
-# ------------------------------------------------- bitonic lane primitives
-def _cmp_halves(s, i, width):
-    """One compare-exchange stage: within each lane group of ``width``,
-    compare lane j against lane j + width/2 and put the greater element
-    (score desc, block id asc on ties — `lax.top_k`'s order) on the
-    left.  All (R, L) fp32/int32."""
-    r, ln = s.shape
-    half = width // 2
-    s4 = s.reshape(r, ln // width, 2, half)
-    i4 = i.reshape(r, ln // width, 2, half)
-    a_s, b_s, a_i, b_i = s4[:, :, 0], s4[:, :, 1], i4[:, :, 0], i4[:, :, 1]
-    a_wins = (a_s > b_s) | ((a_s == b_s) & (a_i < b_i))
-    s = jnp.stack([jnp.where(a_wins, a_s, b_s),
-                   jnp.where(a_wins, b_s, a_s)], axis=2).reshape(r, ln)
-    i = jnp.stack([jnp.where(a_wins, a_i, b_i),
-                   jnp.where(a_wins, b_i, a_i)], axis=2).reshape(r, ln)
+# ------------------------------------------------- bitonic lane network
+class _Lanes:
+    """Lane-exchange helper for (R, C) tiles: ``partner(x, j)`` returns
+    ``x[:, l ^ j]`` for every lane ``l`` (``j`` a power of two < C)."""
+
+    def __init__(self, width: int):
+        self.width = width
+        self.lane = jax.lax.broadcasted_iota(jnp.int32, (SUBLANE, width),
+                                             1)[:1]            # (1, C)
+        self._up = {}
+
+    def up(self, j):
+        """Lanes whose partner ``l ^ j`` sits at ``l + j`` (bit j clear),
+        read off the rotated iota so the mask matches the rotation."""
+        if j not in self._up:
+            lane8 = jax.lax.broadcasted_iota(jnp.int32,
+                                             (SUBLANE, self.width), 1)
+            src = pltpu.roll(lane8, self.width - j, 1)[:1]
+            self._up[j] = src == (self.lane ^ j)
+        return self._up[j]
+
+    def partner(self, x, j):
+        return jnp.where(self.up(j), pltpu.roll(x, self.width - j, 1),
+                         pltpu.roll(x, j, 1))
+
+
+def _cx(s, i, j, desc, lanes: _Lanes):
+    """One compare-exchange stage between lanes l and l ^ j.  Where the
+    pair sorts descending, the lower lane keeps the better element
+    (score desc, block id asc on ties — `lax.top_k`'s order) and the
+    upper lane the worse; elsewhere the reverse.  s (R, C) fp32, i (R, C)
+    int32.  ``desc`` is True/False for every lane, or a power of two
+    ``k > j``: descending where lane bit ``k`` is clear."""
+    ps, pi = lanes.partner(s, j), lanes.partner(i, j)
+    better = (s > ps) | ((s == ps) & (i < pi))
+    # the lane's role, from int32 lane bits only: Mosaic does not
+    # legalize a compare of two bool vectors
+    bit_j = lanes.lane & j                                   # (1, C)
+    if desc is True:
+        take_best = bit_j == 0
+    elif desc is False:
+        take_best = bit_j != 0
+    else:
+        take_best = (bit_j * (desc // j)) == (lanes.lane & desc)
+    best_s, best_i = jnp.where(better, s, ps), jnp.where(better, i, pi)
+    worst_s, worst_i = jnp.where(better, ps, s), jnp.where(better, pi, i)
+    return (jnp.where(take_best, best_s, worst_s),
+            jnp.where(take_best, best_i, worst_i))
+
+
+def _merge(s, i, kp, desc, lanes):
+    """Sort every bitonic kp-lane group in direction ``desc`` (see
+    `_cx`)."""
+    j = kp // 2
+    while j >= 1:
+        s, i = _cx(s, i, j, desc, lanes)
+        j //= 2
     return s, i
 
 
-def _flip_second_half(s, i, width):
-    """Reverse the trailing half of each lane group of ``width`` so two
-    descending-sorted halves become one bitonic (valley) group."""
-    r, ln = s.shape
-    half = width // 2
-    s4 = s.reshape(r, ln // width, 2, half)
-    i4 = i.reshape(r, ln // width, 2, half)
-    s = jnp.stack([s4[:, :, 0], s4[:, :, 1, ::-1]], axis=2).reshape(r, ln)
-    i = jnp.stack([i4[:, :, 0], i4[:, :, 1, ::-1]], axis=2).reshape(r, ln)
+def _local_topk(s, i, kp, lanes):
+    """Stage 1: tile-local top-kp of the C candidate lanes, left in
+    lanes [0, kp) sorted descending (other lanes hold leftovers).
+    Sorts kp-lane groups in alternating directions, then each round
+    folds group pairs 2j apart: a compare-exchange at distance j keeps
+    each pair's top kp (bitonic) in the lower group, and a kp-wide merge
+    sorts it in the direction the next round expects."""
+    c = lanes.width
+    k = 2
+    while k <= kp:
+        s, i = _merge(s, i, k, k, lanes)
+        k *= 2
+    j = kp
+    while j < c:
+        s, i = _cx(s, i, j, True, lanes)
+        s, i = _merge(s, i, kp, 2 * j, lanes)
+        j *= 2
     return s, i
 
 
-def _bitonic_merge_desc(s, i, width):
-    """Sort each bitonic lane group of ``width`` descending
-    (log2(width) compare-exchange stages)."""
-    w = width
-    while w >= 2:
-        s, i = _cmp_halves(s, i, w)
-        w //= 2
-    return s, i
-
-
-def _sort_desc(s, i, width):
-    """Sort each lane group of ``width`` (a power of two) descending."""
-    w = 2
-    while w <= width:
-        s, i = _flip_second_half(s, i, w)
-        s, i = _bitonic_merge_desc(s, i, w)
-        w *= 2
-    return s, i
-
-
-def _local_topk(s, i, kp):
-    """Stage 1: tile-local top-kp of the C candidate lanes.  Sorts
-    kp-lane groups descending, then a bitonic tournament folds the
-    group count in half each round, keeping each merged pair's top kp.
-    s (R, C) fp32, i (R, C) int32, C and kp powers of two."""
-    r, c = s.shape
-    if c < kp:
-        s = jnp.concatenate(
-            [s, jnp.full((r, kp - c), INIT, s.dtype)], axis=1)
-        i = jnp.concatenate(
-            [i, jnp.zeros((r, kp - c), i.dtype)], axis=1)
-        c = kp
-    s, i = _sort_desc(s, i, kp)
-    while c > kp:
-        s, i = _flip_second_half(s, i, 2 * kp)
-        s, i = _bitonic_merge_desc(s, i, 2 * kp)
-        s = s.reshape(r, c // (2 * kp), 2, kp)[:, :, 0].reshape(r, c // 2)
-        i = i.reshape(r, c // (2 * kp), 2, kp)[:, :, 0].reshape(r, c // 2)
-        c //= 2
-    return s, i
-
-
-def _merge_topk(run_s, run_i, loc_s, loc_i):
-    """Stage 2: (k ∪ k) merge — both lists descending-sorted, so
-    run ++ reverse(loc) is bitonic and one merge pass sorts it; the
-    top kp lanes are the new running list."""
-    kp = run_s.shape[1]
-    s = jnp.concatenate([run_s, loc_s[:, ::-1]], axis=1)
-    i = jnp.concatenate([run_i, loc_i[:, ::-1]], axis=1)
-    s, i = _bitonic_merge_desc(s, i, 2 * kp)
-    return s[:, :kp], i[:, :kp]
-
-
-# ------------------------------------------------------------ legacy merge
-def _topk_update(run_s, run_i, cand_s, cand_i, top_k: int):
-    """Merge candidates into the running top-k. All (Tq, ·) fp32/int32.
-    Legacy flat-grid path: k masked max-extraction passes over the
-    (running ∪ candidate) tile."""
-    comb_s = jnp.concatenate([run_s, cand_s], axis=1)
-    comb_i = jnp.concatenate([run_i, cand_i], axis=1)
-    new_s, new_i = [], []
-    for _ in range(top_k):
-        m = jnp.max(comb_s, axis=1, keepdims=True)          # (Tq, 1)
-        hit = comb_s == m
-        first = (jnp.cumsum(hit.astype(jnp.int32), axis=1) == 1) & hit
-        idx = jnp.sum(jnp.where(first, comb_i, 0), axis=1)
-        new_s.append(m[:, 0])
-        new_i.append(idx)
-        comb_s = jnp.where(first, EXTRACTED, comb_s)
-    return jnp.stack(new_s, axis=1), jnp.stack(new_i, axis=1)
-
-
-# ----------------------------------------------------------------- kernels
+# ----------------------------------------------------------------- kernel
 def _flash_topk_kernel(q_ref, c_ref, idx_ref, s_run, i_run, *,
-                       top_k: int, block_size: int, cent_tile: int,
-                       n_blocks: int, n_cent_tiles: int, q_tile: int,
-                       causal: bool, q_pos_offset: int):
-    """Legacy flat grid (B·H, Nq/Tq, ct): per-query-head centroid DMAs
-    and the k-pass extraction merge."""
+                       top_k: int, kp: int, block_size: int,
+                       cent_tile: int, n_blocks: int, n_cent_tiles: int,
+                       q_tile: int, group: int, causal: bool,
+                       q_pos_offset: int):
+    """Grid (B·Hkv, Nq/Tq, ct) (flat: G = 1 over B·H): one centroid-tile
+    DMA serves all G query heads; scores are one (G·Tq, d)·(d, C) MXU
+    matmul; the running top-k lives ascending in lanes [kp, 2kp) of the
+    (G·Tq, C) scratch and updates through the two-stage bitonic merge."""
     ct = pl.program_id(2)
-
-    @pl.when(ct == 0)
-    def _init():
-        s_run[...] = jnp.full_like(s_run, INIT)
-        i_run[...] = jnp.zeros_like(i_run)
-
-    q = q_ref[0].astype(jnp.float32)                       # (Tq, d)
-    cents = c_ref[0].astype(jnp.float32)                   # (C, d)
-    s = jax.lax.dot_general(q, cents, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (Tq, C)
-
-    qt = pl.program_id(1)
-    qpos = (qt * q_tile + q_pos_offset
-            + jax.lax.broadcasted_iota(jnp.int32, (q_tile, cent_tile), 0))
-    cand = (ct * cent_tile
-            + jax.lax.broadcasted_iota(jnp.int32, (q_tile, cent_tile), 1))
-    own = qpos // block_size
-    valid = cand < n_blocks
-    if causal:
-        s = jnp.where(cand > own, NEG_INF, s)
-        s = jnp.where((cand == own) & valid, POS_INF, s)
-    s = jnp.where(valid, s, NEG_INF)
-
-    ns, ni = _topk_update(s_run[...], i_run[...], s, cand, top_k)
-    s_run[...] = ns
-    i_run[...] = ni
-
-    @pl.when(ct == n_cent_tiles - 1)
-    def _emit():
-        final = jnp.where(s_run[...] <= NEG_INF / 2, n_blocks, i_run[...])
-        idx_ref[0] = final.astype(jnp.int32)
-
-
-def _flash_topk_kernel_grouped(q_ref, c_ref, idx_ref, s_run, i_run, *,
-                               top_k: int, kp: int, block_size: int,
-                               cent_tile: int, n_blocks: int,
-                               n_cent_tiles: int, q_tile: int, group: int,
-                               causal: bool, q_pos_offset: int):
-    """Grouped grid (B·Hkv, Nq/Tq, ct): one centroid-tile DMA serves all
-    G query heads; scores are one (G·Tq, d)·(d, C) MXU matmul; the
-    running top-k updates through the two-stage bitonic merge."""
-    ct = pl.program_id(2)
+    lanes = _Lanes(cent_tile)
 
     @pl.when(ct == 0)
     def _init():
@@ -219,9 +163,8 @@ def _flash_topk_kernel_grouped(q_ref, c_ref, idx_ref, s_run, i_run, *,
     # the query position depends only on the row's index inside Tq —
     # every head of the group shares it
     qpos = (qt * q_tile + q_pos_offset
-            + jax.lax.broadcasted_iota(
-                jnp.int32, (group, q_tile, cent_tile), 1
-            ).reshape(rows, cent_tile))
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, cent_tile), 0)
+            % q_tile)
     cand = (ct * cent_tile
             + jax.lax.broadcasted_iota(jnp.int32, (rows, cent_tile), 1))
     own = qpos // block_size
@@ -231,16 +174,24 @@ def _flash_topk_kernel_grouped(q_ref, c_ref, idx_ref, s_run, i_run, *,
         s = jnp.where((cand == own) & valid, POS_INF, s)
     s = jnp.where(valid, s, NEG_INF)
 
-    loc_s, loc_i = _local_topk(s, cand, kp)
-    ns, ni = _merge_topk(s_run[...], i_run[...], loc_s, loc_i)
-    s_run[...] = ns
-    i_run[...] = ni
+    # stage 2: half-cleaner of (tile top-kp desc) ++ (running asc), then
+    # sort the surviving kp lanes ascending and park them in [kp, 2kp)
+    loc_s, loc_i = _local_topk(s, cand, kp, lanes)
+    low = lanes.lane < kp
+    ms = jnp.where(low, loc_s, s_run[...])
+    mi = jnp.where(low, loc_i, i_run[...])
+    ms, mi = _cx(ms, mi, kp, True, lanes)
+    ms, mi = _merge(ms, mi, kp, False, lanes)
+    s_run[...] = lanes.partner(ms, kp)
+    i_run[...] = lanes.partner(mi, kp)
 
     @pl.when(ct == n_cent_tiles - 1)
     def _emit():
-        final = jnp.where(s_run[...] <= NEG_INF / 2, n_blocks, i_run[...])
-        idx_ref[...] = final.reshape(
-            group, q_tile, kp)[:, :, :top_k].astype(jnp.int32)
+        # lanes [0, kp) ascending -> one merge pass sorts them descending
+        fs, fi = _merge(ms, mi, kp, True, lanes)
+        final = jnp.where(fs <= NEG_INF / 2, n_blocks, fi)
+        for g in range(group):
+            idx_ref[g] = final[g * q_tile:(g + 1) * q_tile, :top_k]
 
 
 # ----------------------------------------------------------------- wrapper
@@ -268,65 +219,53 @@ def flash_topk(q: jax.Array, centroids: jax.Array, top_k: int,
     assert bh // h * (h // group) == bkv
     q_tile = min(q_tile, nq)
     assert nq % q_tile == 0, (nq, q_tile)
+    if cent_tile & (cent_tile - 1):
+        raise ValueError(
+            f"flash_topk needs a power-of-two cent_tile (the bitonic "
+            f"tournament folds candidate lanes in halves); got "
+            f"{cent_tile}")
+    kp = max(SUBLANE, next_pow2(top_k))   # merge lists padded to the
+    #                                       sublane grain / power of two
+    cent_tile = max(cent_tile, 2 * kp)    # room for the (k ∪ k) merge
     n_cent_tiles = -(-nb // cent_tile)
     pad = n_cent_tiles * cent_tile - nb
     if pad:
         centroids = jnp.pad(centroids, ((0, 0), (0, pad), (0, 0)))
 
     if grid == "flat":
-        def kv_index(hh, qt, ct):
+        # one query head per row block, its own kv head's centroids
+        g_blk = 1
+
+        def cent_index(hh, qt, ct):
             return ((hh // h) * (h // group) + (hh % h) // group, ct, 0)
+    else:
+        # dim 0 enumerates (batch, kv head): the G query heads of a
+        # group are contiguous rows of q, and the dim-0 block index
+        # b·Hkv + kv is exactly the centroid row
+        g_blk = group
 
-        kernel = functools.partial(
-            _flash_topk_kernel, top_k=top_k, block_size=block_size,
-            cent_tile=cent_tile, n_blocks=nb, n_cent_tiles=n_cent_tiles,
-            q_tile=q_tile, causal=causal, q_pos_offset=q_pos_offset)
-        return pl.pallas_call(
-            kernel,
-            grid=(bh, nq // q_tile, n_cent_tiles),
-            in_specs=[
-                pl.BlockSpec((1, q_tile, d),
-                             lambda hh, qt, ct: (hh, qt, 0)),
-                pl.BlockSpec((1, cent_tile, d), kv_index),
-            ],
-            out_specs=pl.BlockSpec((1, q_tile, top_k),
-                                   lambda hh, qt, ct: (hh, qt, 0)),
-            out_shape=jax.ShapeDtypeStruct((bh, nq, top_k), jnp.int32),
-            scratch_shapes=[pltpu.VMEM((q_tile, top_k), jnp.float32),
-                            pltpu.VMEM((q_tile, top_k), jnp.int32)],
-            interpret=interpret,
-        )(q, centroids)
+        def cent_index(gg, qt, ct):
+            return (gg, ct, 0)
 
-    # grouped grid: dim 0 enumerates (batch, kv head) — the G query
-    # heads of a group are contiguous rows of q, and the dim-0 block
-    # index b·Hkv + kv is exactly the centroid row
-    if cent_tile & (cent_tile - 1):
-        raise ValueError(
-            f"grouped flash_topk needs a power-of-two cent_tile (the "
-            f"bitonic tournament folds candidate lanes in halves); got "
-            f"{cent_tile}")
-    if not interpret:
-        check_topk_tiling(cent_tile, q_tile, d, q.dtype)
-    kp = max(SUBLANE, next_pow2(top_k))   # merge lists padded to the
-    #                                       sublane grain / power of two
     kernel = functools.partial(
-        _flash_topk_kernel_grouped, top_k=top_k, kp=kp,
-        block_size=block_size, cent_tile=cent_tile, n_blocks=nb,
-        n_cent_tiles=n_cent_tiles, q_tile=q_tile, group=group,
-        causal=causal, q_pos_offset=q_pos_offset)
+        _flash_topk_kernel, top_k=top_k, kp=kp, block_size=block_size,
+        cent_tile=cent_tile, n_blocks=nb, n_cent_tiles=n_cent_tiles,
+        q_tile=q_tile, group=g_blk, causal=causal,
+        q_pos_offset=q_pos_offset)
+    in_specs = [pl.BlockSpec((g_blk, q_tile, d),
+                             lambda gg, qt, ct: (gg, qt, 0)),
+                pl.BlockSpec((1, cent_tile, d), cent_index)]
+    out_spec = pl.BlockSpec((g_blk, q_tile, top_k),
+                            lambda gg, qt, ct: (gg, qt, 0))
+    out_shape = jax.ShapeDtypeStruct((bh, nq, top_k), jnp.int32)
+    if not interpret:
+        check_blocks("flash_topk", ("q", "centroids", "idx"),
+                     (*in_specs, out_spec), (q, centroids, out_shape))
     return pl.pallas_call(
         kernel,
-        grid=(bh // group, nq // q_tile, n_cent_tiles),
-        in_specs=[
-            pl.BlockSpec((group, q_tile, d),
-                         lambda gg, qt, ct: (gg, qt, 0)),
-            pl.BlockSpec((1, cent_tile, d),
-                         lambda gg, qt, ct: (gg, ct, 0)),
-        ],
-        out_specs=pl.BlockSpec((group, q_tile, top_k),
-                               lambda gg, qt, ct: (gg, qt, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, nq, top_k), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((group * q_tile, kp), jnp.float32),
-                        pltpu.VMEM((group * q_tile, kp), jnp.int32)],
+        grid=(bh // g_blk, nq // q_tile, n_cent_tiles),
+        in_specs=in_specs, out_specs=out_spec, out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((g_blk * q_tile, cent_tile), jnp.float32),
+                        pltpu.VMEM((g_blk * q_tile, cent_tile), jnp.int32)],
         interpret=interpret,
     )(q, centroids)

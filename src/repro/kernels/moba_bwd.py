@@ -32,9 +32,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
-from repro.kernels.tiling import check_moba_tiling, default_kb_tile
+from repro.kernels.tiling import check_blocks, resolve_kb_tile
 
 NEG_INF = -1e30
+# kernel operands in pallas_call order, named in block-contract errors
+_OPERANDS = ("q", "q_pos", "do", "lse", "delta", "k", "v",
+             "dq", "dk", "dv")
 
 
 def _bwd_kernel(tb_ref, qs_ref, qpos_ref, do_ref, lse_ref, delta_ref,
@@ -54,26 +57,26 @@ def _bwd_kernel(tb_ref, qs_ref, qpos_ref, do_ref, lse_ref, delta_ref,
     do = do_ref[0].astype(jnp.float32)           # (Tq, d)
     kb = k_ref[0, 0].astype(jnp.float32)         # (B, d)
     vb = v_ref[0, 0].astype(jnp.float32)
-    qpos = qpos_ref[0]
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    qpos = qpos_ref[0]                           # (Tq, 1) int32
+    lse = lse_ref[0]                             # (Tq, 1)
+    delta = delta_ref[0]                         # (Tq, 1)
     tq = q.shape[0]
 
     s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     kpos = (blk * block_size
             + jax.lax.broadcasted_iota(jnp.int32, (tq, block_size), 1))
-    mask = (qpos[:, None] >= 0) & (blk < n_blocks) & (kpos < n_tokens)
+    mask = (qpos >= 0) & (blk < n_blocks) & (kpos < n_tokens)
     if causal:
-        mask &= kpos <= qpos[:, None]
+        mask &= kpos <= qpos
     # true post-merge probabilities: exp(s - lse_q)
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)     # (Tq, B)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)              # (Tq, B)
 
     dv_c = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)  # (B, d)
     dp = jax.lax.dot_general(do, vb, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)    # (Tq, B)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dq_c = jax.lax.dot_general(ds, kb, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)  # (Tq, d)
     dk_c = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
@@ -115,25 +118,25 @@ def _bwd_kernel_tiled(tb_ref, qs_ref, qpos_ref, do_ref, lse_ref, delta_ref,
     do = do_ref[0].astype(jnp.float32)           # (Tq, d)
     kbt = k_ref[0, 0].astype(jnp.float32)        # (kb_tile, d)
     vbt = v_ref[0, 0].astype(jnp.float32)
-    qpos = qpos_ref[0]
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    qpos = qpos_ref[0]                           # (Tq, 1) int32
+    lse = lse_ref[0]                             # (Tq, 1)
+    delta = delta_ref[0]                         # (Tq, 1)
     tq = q.shape[0]
 
     s = jax.lax.dot_general(q, kbt, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     kpos = (blk * block_size + kb * kb_tile
             + jax.lax.broadcasted_iota(jnp.int32, (tq, kb_tile), 1))
-    mask = (qpos[:, None] >= 0) & (blk < n_blocks) & (kpos < n_tokens)
+    mask = (qpos >= 0) & (blk < n_blocks) & (kpos < n_tokens)
     if causal:
-        mask &= kpos <= qpos[:, None]
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)     # (Tq, kbt)
+        mask &= kpos <= qpos
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)              # (Tq, kbt)
 
     dv_c = jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, vbt, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dq_c = jax.lax.dot_general(ds, kbt, (((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
     dk_c = jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
@@ -192,6 +195,10 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
     n_tiles = L // q_tile
     h = num_q_heads
 
+    # per-query vectors as (BH, L, 1) columns (see `moba_fwd`)
+    q_pos = q_pos[..., None]
+    lse_sorted = lse_sorted[..., None]
+    delta_sorted = delta_sorted[..., None]
     out_shape = [
         jax.ShapeDtypeStruct((bh, L, d), jnp.float32),
         jax.ShapeDtypeStruct((bh, nb, bs, d), jnp.float32),
@@ -212,10 +219,10 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
             grid=(bh, n_tiles),
             in_specs=[
                 pl.BlockSpec((1, q_tile, d), lambda bhi, t, tb: (bhi, t, 0)),
-                pl.BlockSpec((1, q_tile), lambda bhi, t, tb: (bhi, t)),
+                pl.BlockSpec((1, q_tile, 1), lambda bhi, t, tb: (bhi, t, 0)),
                 pl.BlockSpec((1, q_tile, d), lambda bhi, t, tb: (bhi, t, 0)),
-                pl.BlockSpec((1, q_tile), lambda bhi, t, tb: (bhi, t)),
-                pl.BlockSpec((1, q_tile), lambda bhi, t, tb: (bhi, t)),
+                pl.BlockSpec((1, q_tile, 1), lambda bhi, t, tb: (bhi, t, 0)),
+                pl.BlockSpec((1, q_tile, 1), lambda bhi, t, tb: (bhi, t, 0)),
                 pl.BlockSpec((1, 1, bs, d), kv_index),
                 pl.BlockSpec((1, 1, bs, d), kv_index),
             ],
@@ -225,6 +232,11 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
                 pl.BlockSpec((1, 1, bs, d), dkv_index),
             ],
         )
+        if not interpret:
+            check_blocks("moba_bwd", _OPERANDS,
+                         [*grid_spec.in_specs, *grid_spec.out_specs],
+                         [q_sorted, q_pos, do_sorted, lse_sorted,
+                          delta_sorted, k_blocks, v_blocks, *out_shape])
         kernel = functools.partial(
             _bwd_kernel, scale=scale, block_size=block_size, n_blocks=nb,
             n_tokens=n_tokens, causal=causal)
@@ -234,10 +246,7 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
         )(tile_block, q_sorted, q_pos, do_sorted, lse_sorted, delta_sorted,
           k_blocks, v_blocks)
 
-    kb_tile = min(kb_tile or default_kb_tile(bs), bs)
-    if not interpret:
-        check_moba_tiling(bs, kb_tile, q_tile, d, k_blocks.dtype)
-    assert bs % kb_tile == 0, (bs, kb_tile)
+    kb_tile = resolve_kb_tile(kb_tile, bs)
     n_kb = bs // kb_tile
 
     def kv_index(bhi, t, kb, tb_ref):
@@ -258,10 +267,13 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
         grid=(bh, n_tiles, n_kb),
         in_specs=[
             pl.BlockSpec((1, q_tile, d), lambda bhi, t, kb, tb: (bhi, t, 0)),
-            pl.BlockSpec((1, q_tile), lambda bhi, t, kb, tb: (bhi, t)),
+            pl.BlockSpec((1, q_tile, 1),
+                         lambda bhi, t, kb, tb: (bhi, t, 0)),
             pl.BlockSpec((1, q_tile, d), lambda bhi, t, kb, tb: (bhi, t, 0)),
-            pl.BlockSpec((1, q_tile), lambda bhi, t, kb, tb: (bhi, t)),
-            pl.BlockSpec((1, q_tile), lambda bhi, t, kb, tb: (bhi, t)),
+            pl.BlockSpec((1, q_tile, 1),
+                         lambda bhi, t, kb, tb: (bhi, t, 0)),
+            pl.BlockSpec((1, q_tile, 1),
+                         lambda bhi, t, kb, tb: (bhi, t, 0)),
             pl.BlockSpec((1, 1, kb_tile, d), kv_index),
             pl.BlockSpec((1, 1, kb_tile, d), kv_index),
         ],
@@ -275,6 +287,11 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
             pltpu.VMEM((bs, d), jnp.float32),
         ],
     )
+    if not interpret:
+        check_blocks("moba_bwd", _OPERANDS,
+                     [*grid_spec.in_specs, *grid_spec.out_specs],
+                     [q_sorted, q_pos, do_sorted, lse_sorted, delta_sorted,
+                      k_t, v_t, *out_shape])
     kernel = functools.partial(
         _bwd_kernel_tiled, scale=scale, block_size=block_size,
         kb_tile=kb_tile, n_kb=n_kb, n_blocks=nb, n_tokens=n_tokens,

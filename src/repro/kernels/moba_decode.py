@@ -36,11 +36,17 @@ when the group's heads agree), with no densified intermediate: the
 memory-bound decode shape the paper's small-block regime needs
 (FlashMoBA, Table "kernel"; PAPERS.md decode-bottleneck).
 
-Compiled lowering (``interpret=False``, see `kernels.runtime`) requires
-(8, 128)-tileable pages: ``page_size`` a multiple of the dtype sublane
-grain and ``head_dim`` a multiple of 128 — enforced by explicit
-asserts; interpret mode accepts any shape (CPU CI runs the small test
-geometries there).
+Block layouts (DESIGN.md §5): the pool is head-major
+``(P, Hkv, ps, d)``, so each step streams one kv head's page as a
+``(1, 1, ps, d)`` block — its last two dims are the full ``(ps, d)``
+page, the shape Mosaic accepts (a block's last two dims must be whole
+(8, 128) tiles or the full array dims).  The grouped grid's per-head
+token offsets arrive as one ``(1, Gp, U)`` block per kv head (full U)
+and the step's column is selected in VMEM; the flat grid carries q and
+its output as ``(B·H, 1, d)`` so the ``(1, 1, d)`` block is full in its
+last two dims.  Every block is therefore whole for any ``page_size``
+and ``head_dim``; the compiled wrappers still pass their specs through
+`kernels.tiling.check_blocks` so a future layout edit fails shaped.
 
 Equivalence: same selection (shared router) and same softmax up to
 fp32 reduction order → matches the XLA path within 1e-3
@@ -59,13 +65,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.configs.base import MoBAConfig
 from repro.core.moba import moba_paged_route
 from repro.kernels.runtime import resolve_interpret
-from repro.kernels.tiling import (  # noqa: F401  (re-exported names)
-    LANE,
-    SUBLANE,
-    check_decode_tiling,
-    round_up as _round_up,
-    sublane as _sublane,
-)
+from repro.kernels.tiling import check_blocks, round_up, sublane
 
 NEG_INF = -1e30
 
@@ -123,13 +123,17 @@ def _decode_kernel_grouped(phys_ref, kvl_ref, ksc_ref, vsc_ref, q_ref,
         l_acc[...] = jnp.zeros_like(l_acc)
 
     q = q_ref[0].astype(jnp.float32)              # (Gp, d)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32) * ksc_ref[bh, uu]  # (ps, d)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32) * vsc_ref[bh, uu]
+    kb = k_ref[0, 0].astype(jnp.float32) * ksc_ref[bh, uu]  # (ps, d)
+    vb = v_ref[0, 0].astype(jnp.float32) * vsc_ref[bh, uu]
 
     s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (Gp, ps)
     s = s * scale
-    base = base_ref[0, :, :]                      # (Gp, 1) int32
+    # this step's column of the resident (Gp, U) offset table
+    base_all = base_ref[0]                        # (Gp, U) int32
+    col = jax.lax.broadcasted_iota(jnp.int32, base_all.shape, 1)
+    base = jnp.sum(jnp.where(col == uu, base_all, 0), axis=1,
+                   keepdims=True)                 # (Gp, 1)
     pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
     mask = pos < kvl_ref[bh]                      # (Gp, ps)
     s = jnp.where(mask, s, NEG_INF)
@@ -156,7 +160,7 @@ def _decode_grouped(q, pages_k, pages_v, block_table, kv_len, idx,
                     sel_valid, *, scale: float, interpret: bool,
                     scales_k=None, scales_v=None):
     b, h, _, d = q.shape
-    num_pages, ps, hkv, _ = pages_k.shape
+    num_pages, hkv, ps, _ = pages_k.shape
     npg = block_table.shape[1]
     g = h // hkv
     tk = idx.shape[-1]
@@ -192,7 +196,7 @@ def _decode_grouped(q, pages_k, pages_v, block_table, kv_len, idx,
     # pad the group dim to the q-dtype sublane grain so the q block,
     # scratch and output are whole (sublane, 128) tiles; padded rows
     # carry the sentinel offset, so they mask out and emit zeros
-    gp = _round_up(g, _sublane(q.dtype))
+    gp = round_up(g, sublane(q.dtype))
     q_f = jnp.zeros((b * hkv, gp, d), q.dtype)
     q_f = q_f.at[:, :g].set(q[:, :, 0, :].reshape(b * hkv, g, d))
     base_f = jnp.full((b * hkv, gp, cap), npg * ps, jnp.int32)
@@ -202,16 +206,16 @@ def _decode_grouped(q, pages_k, pages_v, block_table, kv_len, idx,
     kvl_f = kvl_f.astype(jnp.int32)
 
     def kv_index(bh, uu, phys_ref, kvl_ref, ksc_ref, vsc_ref):
-        return (phys_ref[bh, uu], 0, bh % hkv, 0)
+        return (phys_ref[bh, uu], bh % hkv, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b * hkv, cap),
         in_specs=[
             pl.BlockSpec((1, gp, d), lambda bh, uu, *_: (bh, 0, 0)),
-            pl.BlockSpec((1, gp, 1), lambda bh, uu, *_: (bh, 0, uu)),
-            pl.BlockSpec((1, ps, 1, d), kv_index),
-            pl.BlockSpec((1, ps, 1, d), kv_index),
+            pl.BlockSpec((1, gp, cap), lambda bh, uu, *_: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, ps, d), kv_index),
+            pl.BlockSpec((1, 1, ps, d), kv_index),
         ],
         out_specs=pl.BlockSpec((1, gp, d), lambda bh, uu, *_: (bh, 0, 0)),
         scratch_shapes=[
@@ -220,12 +224,16 @@ def _decode_grouped(q, pages_k, pages_v, block_table, kv_len, idx,
             pltpu.VMEM((gp, 1), jnp.float32),
         ],
     )
+    out_shape = jax.ShapeDtypeStruct((b * hkv, gp, d), jnp.float32)
+    if not interpret:
+        check_blocks("paged decode (grouped)",
+                     ("q", "base", "pages_k", "pages_v", "out"),
+                     (*grid_spec.in_specs, grid_spec.out_specs),
+                     (q_f, base_f, pages_k, pages_v, out_shape))
     kernel = functools.partial(_decode_kernel_grouped, page_size=ps,
                                n_union=cap, scale=float(scale))
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * hkv, gp, d), jnp.float32),
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
         interpret=interpret,
     )(phys_f, kvl_f, ksc_f, vsc_f, q_f, base_f, pages_k, pages_v)
     return out[:, :g].reshape(b, h, 1, d).astype(q.dtype)
@@ -254,9 +262,9 @@ def _decode_kernel_flat(phys_ref, base_ref, kvl_ref, ksc_ref, vsc_ref,
         m_acc[0, 0] = NEG_INF
         l_acc[0, 0] = 0.0
 
-    q = q_ref[...].astype(jnp.float32)                 # (1, d)
-    kb = k_ref[0, :, 0, :].astype(jnp.float32) * ksc_ref[bh, kk]  # (ps, d)
-    vb = v_ref[0, :, 0, :].astype(jnp.float32) * vsc_ref[bh, kk]
+    q = q_ref[0].astype(jnp.float32)                   # (1, d)
+    kb = k_ref[0, 0].astype(jnp.float32) * ksc_ref[bh, kk]  # (ps, d)
+    vb = v_ref[0, 0].astype(jnp.float32) * vsc_ref[bh, kk]
 
     s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (1, ps)
@@ -280,15 +288,15 @@ def _decode_kernel_flat(phys_ref, base_ref, kvl_ref, ksc_ref, vsc_ref,
     @pl.when(kk == top_k - 1)
     def _emit():
         l = l_acc[0, 0]
-        o_ref[...] = (o_acc[...]
-                      / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+        o_ref[0] = (o_acc[...]
+                    / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
 
 
 def _decode_flat(q, pages_k, pages_v, block_table, kv_len, idx, sel_valid,
                  *, scale: float, interpret: bool,
                  scales_k=None, scales_v=None):
     b, h, _, d = q.shape
-    num_pages, ps, hkv, _ = pages_k.shape
+    num_pages, hkv, ps, _ = pages_k.shape
     npg = block_table.shape[1]
     g = h // hkv
     tk = idx.shape[-1]
@@ -305,7 +313,7 @@ def _decode_flat(q, pages_k, pages_v, block_table, kv_len, idx, sel_valid,
     base_f = base[:, :, :, 0, :].reshape(b * h, tk).astype(jnp.int32)
     kvl_f = jnp.broadcast_to(kv_len[:, None], (b, h)).reshape(-1)
     kvl_f = kvl_f.astype(jnp.int32)
-    q_f = q[:, :, 0, :].reshape(b * h, d)
+    q_f = q.reshape(b * h, 1, d)
     if scales_k is None:
         ksc_f = jnp.ones((b * h, tk), jnp.float32)
         vsc_f = ksc_f
@@ -315,29 +323,33 @@ def _decode_flat(q, pages_k, pages_v, block_table, kv_len, idx, sel_valid,
         vsc_f = scales_v[phys[:, :, :, 0, :], hsel].reshape(b * h, tk)
 
     def kv_index(bh, kk, phys_ref, base_ref, kvl_ref, ksc_ref, vsc_ref):
-        return (phys_ref[bh, kk], 0, (bh % h) // g, 0)
+        return (phys_ref[bh, kk], (bh % h) // g, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b * h, tk),
         in_specs=[
-            pl.BlockSpec((1, d), lambda bh, kk, *_: (bh, 0)),
-            pl.BlockSpec((1, ps, 1, d), kv_index),
-            pl.BlockSpec((1, ps, 1, d), kv_index),
+            pl.BlockSpec((1, 1, d), lambda bh, kk, *_: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, ps, d), kv_index),
+            pl.BlockSpec((1, 1, ps, d), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda bh, kk, *_: (bh, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda bh, kk, *_: (bh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
             pltpu.SMEM((1, 1), jnp.float32),
             pltpu.SMEM((1, 1), jnp.float32),
         ],
     )
+    out_shape = jax.ShapeDtypeStruct((b * h, 1, d), jnp.float32)
+    if not interpret:
+        check_blocks("paged decode (flat)",
+                     ("q", "pages_k", "pages_v", "out"),
+                     (*grid_spec.in_specs, grid_spec.out_specs),
+                     (q_f, pages_k, pages_v, out_shape))
     kernel = functools.partial(_decode_kernel_flat, page_size=ps,
                                top_k=tk, scale=float(scale))
     out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, d), jnp.float32),
+        kernel, grid_spec=grid_spec, out_shape=out_shape,
         interpret=interpret,
     )(phys_f, base_f, kvl_f, ksc_f, vsc_f, q_f, pages_k, pages_v)
     return out.reshape(b, h, 1, d).astype(q.dtype)
@@ -358,7 +370,7 @@ def moba_paged_decode_pallas(q: jax.Array, pages_k: jax.Array,
     """Drop-in for `core.moba.moba_paged_decode_attention` (same contract):
 
     q:           (B, H, 1, d)
-    pages_k/v:   (P, page_size, Hkv, d) shared pool (one layer slot)
+    pages_k/v:   (P, Hkv, page_size, d) shared pool (one layer slot)
     centroids:   (P, Hkv, d) fp32 per-page centroid cache
     block_table: (B, npg) int32 physical page ids, -1 = unassigned
     kv_len:      (B,) int32 post-append valid lengths
@@ -378,12 +390,10 @@ def moba_paged_decode_pallas(q: jax.Array, pages_k: jax.Array,
         raise ValueError(f"unknown decode grid {grid!r}: "
                          f"expected 'grouped' or 'flat'")
     _, _, _, d = q.shape
-    _, ps, _, _ = pages_k.shape
+    _, _, ps, _ = pages_k.shape
     if scale is None:
         scale = 1.0 / (d ** 0.5)
     interpret = resolve_interpret(interpret)
-    if not interpret and grid == "grouped":
-        check_decode_tiling(ps, d, pages_k.dtype)
 
     # Per-head budgets (`head_top_k`, adaptive routing) truncate the
     # score-sorted selection inside the shared route: the flat grid sees

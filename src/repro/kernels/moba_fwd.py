@@ -35,9 +35,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.runtime import resolve_interpret
-from repro.kernels.tiling import check_moba_tiling, default_kb_tile
+from repro.kernels.tiling import check_blocks, resolve_kb_tile
 
 NEG_INF = -1e30
+# kernel operands in pallas_call order, named in block-contract errors
+_OPERANDS = ("q", "q_pos", "k", "v", "o", "m", "l")
 
 
 def _fwd_kernel(tb_ref, qs_ref, qpos_ref, k_ref, v_ref,
@@ -52,7 +54,7 @@ def _fwd_kernel(tb_ref, qs_ref, qpos_ref, k_ref, v_ref,
     q = qs_ref[0].astype(jnp.float32)            # (Tq, d)
     kb = k_ref[0, 0].astype(jnp.float32)         # (B, d)
     vb = v_ref[0, 0].astype(jnp.float32)
-    qpos = qpos_ref[0]                           # (Tq,) int32
+    qpos = qpos_ref[0]                           # (Tq, 1) int32
     tq = q.shape[0]
 
     s = jax.lax.dot_general(q, kb, (((1,), (1,)), ((), ())),
@@ -60,19 +62,19 @@ def _fwd_kernel(tb_ref, qs_ref, qpos_ref, k_ref, v_ref,
     s = s * scale
     kpos = (blk * block_size
             + jax.lax.broadcasted_iota(jnp.int32, (tq, block_size), 1))
-    mask = (qpos[:, None] >= 0) & (blk < n_blocks) & (kpos < n_tokens)
+    mask = (qpos >= 0) & (blk < n_blocks) & (kpos < n_tokens)
     if causal:
-        mask &= kpos <= qpos[:, None]
+        mask &= kpos <= qpos
     s = jnp.where(mask, s, NEG_INF)
 
-    m = jnp.max(s, axis=1)
+    m = jnp.max(s, axis=1, keepdims=True)                     # (Tq, 1)
     m_safe = jnp.maximum(m, NEG_INF / 2)
-    p = jnp.exp(s - m_safe[:, None]) * mask.astype(jnp.float32)
-    l = jnp.sum(p, axis=1)
+    p = jnp.exp(s - m_safe) * mask.astype(jnp.float32)
+    l = jnp.sum(p, axis=1, keepdims=True)
     o = jax.lax.dot_general(p, vb, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
 
-    any_valid = jnp.max(mask.astype(jnp.float32), axis=1)
+    any_valid = jnp.max(mask.astype(jnp.float32), axis=1, keepdims=True)
     o_ref[0] = o
     m_ref[0] = jnp.where(any_valid > 0, m, NEG_INF)
     l_ref[0] = l
@@ -102,16 +104,16 @@ def _fwd_kernel_tiled(tb_ref, qs_ref, qpos_ref, k_ref, v_ref,
     q = qs_ref[0].astype(jnp.float32)            # (Tq, d)
     kbt = k_ref[0, 0].astype(jnp.float32)        # (kb_tile, d)
     vbt = v_ref[0, 0].astype(jnp.float32)
-    qpos = qpos_ref[0]                           # (Tq,) int32
+    qpos = qpos_ref[0]                           # (Tq, 1) int32
     tq = q.shape[0]
 
     s = jax.lax.dot_general(q, kbt, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     kpos = (blk * block_size + kb * kb_tile
             + jax.lax.broadcasted_iota(jnp.int32, (tq, kb_tile), 1))
-    mask = (qpos[:, None] >= 0) & (blk < n_blocks) & (kpos < n_tokens)
+    mask = (qpos >= 0) & (blk < n_blocks) & (kpos < n_tokens)
     if causal:
-        mask &= kpos <= qpos[:, None]
+        mask &= kpos <= qpos
     s = jnp.where(mask, s, NEG_INF)
 
     # online-softmax merge into the running (o, m, l).  With every lane
@@ -132,8 +134,8 @@ def _fwd_kernel_tiled(tb_ref, qs_ref, qpos_ref, k_ref, v_ref,
     def _emit():
         l = l_acc[...]
         o_ref[0] = o_acc[...]
-        m_ref[0] = jnp.where(l[:, 0] > 0, m_acc[:, 0], NEG_INF)
-        l_ref[0] = l[:, 0]
+        m_ref[0] = jnp.where(l > 0, m_acc[...], NEG_INF)
+        l_ref[0] = l
 
 
 def moba_fwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
@@ -165,10 +167,14 @@ def moba_fwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
     assert L % q_tile == 0 and tile_block.shape == (bh, n_tiles)
     h = num_q_heads
 
+    # per-query vectors travel as (BH, L, 1) columns: a (1, q_tile, 1)
+    # block is whole in its last two dims, and the column broadcasts
+    # against the (Tq, kb) score tile without a relayout
+    q_pos = q_pos[..., None]
     out_shape = [
         jax.ShapeDtypeStruct((bh, L, d), jnp.float32),
-        jax.ShapeDtypeStruct((bh, L), jnp.float32),
-        jax.ShapeDtypeStruct((bh, L), jnp.float32),
+        jax.ShapeDtypeStruct((bh, L, 1), jnp.float32),
+        jax.ShapeDtypeStruct((bh, L, 1), jnp.float32),
     ]
 
     if grid == "flat":
@@ -182,28 +188,30 @@ def moba_fwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
             grid=(bh, n_tiles),
             in_specs=[
                 pl.BlockSpec((1, q_tile, d), lambda bhi, t, tb: (bhi, t, 0)),
-                pl.BlockSpec((1, q_tile), lambda bhi, t, tb: (bhi, t)),
+                pl.BlockSpec((1, q_tile, 1), lambda bhi, t, tb: (bhi, t, 0)),
                 pl.BlockSpec((1, 1, bs, d), kv_index),
                 pl.BlockSpec((1, 1, bs, d), kv_index),
             ],
             out_specs=[
                 pl.BlockSpec((1, q_tile, d), lambda bhi, t, tb: (bhi, t, 0)),
-                pl.BlockSpec((1, q_tile), lambda bhi, t, tb: (bhi, t)),
-                pl.BlockSpec((1, q_tile), lambda bhi, t, tb: (bhi, t)),
+                pl.BlockSpec((1, q_tile, 1), lambda bhi, t, tb: (bhi, t, 0)),
+                pl.BlockSpec((1, q_tile, 1), lambda bhi, t, tb: (bhi, t, 0)),
             ],
         )
+        if not interpret:
+            check_blocks("moba_fwd", _OPERANDS,
+                         [*grid_spec.in_specs, *grid_spec.out_specs],
+                         [q_sorted, q_pos, k_blocks, v_blocks, *out_shape])
         kernel = functools.partial(
             _fwd_kernel, scale=scale, block_size=block_size, n_blocks=nb,
             n_tokens=n_tokens, causal=causal)
-        return pl.pallas_call(
+        o, m, l = pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
             interpret=interpret,
         )(tile_block, q_sorted, q_pos, k_blocks, v_blocks)
+        return o, m[..., 0], l[..., 0]
 
-    kb_tile = min(kb_tile or default_kb_tile(bs), bs)
-    if not interpret:
-        check_moba_tiling(bs, kb_tile, q_tile, d, k_blocks.dtype)
-    assert bs % kb_tile == 0, (bs, kb_tile)
+    kb_tile = resolve_kb_tile(kb_tile, bs)
     n_kb = bs // kb_tile
 
     def kv_index(bhi, t, kb, tb_ref):
@@ -222,14 +230,14 @@ def moba_fwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
         grid=(bh, n_tiles, n_kb),
         in_specs=[
             pl.BlockSpec((1, q_tile, d), lambda bhi, t, kb, tb: (bhi, t, 0)),
-            pl.BlockSpec((1, q_tile), lambda bhi, t, kb, tb: (bhi, t)),
+            pl.BlockSpec((1, q_tile, 1), lambda bhi, t, kb, tb: (bhi, t, 0)),
             pl.BlockSpec((1, 1, kb_tile, d), kv_index),
             pl.BlockSpec((1, 1, kb_tile, d), kv_index),
         ],
         out_specs=[
             pl.BlockSpec((1, q_tile, d), lambda bhi, t, kb, tb: (bhi, t, 0)),
-            pl.BlockSpec((1, q_tile), lambda bhi, t, kb, tb: (bhi, t)),
-            pl.BlockSpec((1, q_tile), lambda bhi, t, kb, tb: (bhi, t)),
+            pl.BlockSpec((1, q_tile, 1), lambda bhi, t, kb, tb: (bhi, t, 0)),
+            pl.BlockSpec((1, q_tile, 1), lambda bhi, t, kb, tb: (bhi, t, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((q_tile, d), jnp.float32),
@@ -237,11 +245,16 @@ def moba_fwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
             pltpu.VMEM((q_tile, 1), jnp.float32),
         ],
     )
+    if not interpret:
+        check_blocks("moba_fwd", _OPERANDS,
+                     [*grid_spec.in_specs, *grid_spec.out_specs],
+                     [q_sorted, q_pos, k_t, v_t, *out_shape])
     kernel = functools.partial(
         _fwd_kernel_tiled, scale=scale, block_size=block_size,
         kb_tile=kb_tile, n_kb=n_kb, n_blocks=nb, n_tokens=n_tokens,
         causal=causal)
-    return pl.pallas_call(
+    o, m, l = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
         interpret=interpret,
     )(tile_block, q_sorted, q_pos, k_t, v_t)
+    return o, m[..., 0], l[..., 0]

@@ -1,34 +1,29 @@
-"""Shared compiled-mode tiling contracts for the Pallas kernels.
+"""Shared geometry helpers and the compiled-mode block contract of the
+Pallas kernels.
 
-Mosaic lowers VMEM blocks in (sublane × lane) tiles — (8, 128) for fp32,
-(16, 128) for bf16, (32, 128) for int8/fp8.  A block whose trailing two
-dims do not decompose into whole tiles either pads silently (wasting
-VMEM/bandwidth) or fails deep inside Mosaic with an unshaped error.  The
-kernels therefore validate their geometry *here*, before any
-``pallas_call``, and raise a shaped ``ValueError`` naming the violating
-dimension and the remediation (DESIGN.md §2/§5).
-
-Contracts:
-
-* :func:`check_decode_tiling` — the grouped paged-decode grid
-  (``kernels/moba_decode.py``): (page_size, head_dim) pages.
-* :func:`check_moba_tiling` — the kb-tiled training grids
-  (``kernels/moba_fwd.py`` / ``kernels/moba_bwd.py``): the
-  (q_tile, head_dim) query block and the (kb_tile, head_dim) key-block
-  tile streamed per grid step.
-* :func:`check_topk_tiling` — the grouped Flash-TopK grid
-  (``kernels/flash_topk.py``): the (q_tile, cent_tile) score tile and
-  the (cent_tile, head_dim) centroid block.
+Mosaic, the TPU's kernel compiler, accepts a VMEM block only if each of
+its last two dims is a multiple of (8, 128) — for every dtype — or equals
+that dim of the array it tiles; anything else fails deep inside the
+lowering with an unshaped error.  Every kernel wrapper therefore hands
+its block specs to :func:`check_blocks` before a compiled
+``pallas_call``, which raises a shaped ``ValueError`` naming the operand,
+its block and the violated dim (DESIGN.md §2/§5).  The kernels lay out
+their operands so that most blocks are whole by construction (per-query
+vectors as ``(BH, L, 1)`` columns, head-major page pools, centroid tiles
+of ``sublane(dtype)`` rows); what a caller can still get wrong is a
+``q_tile`` that is neither a multiple of 8 nor the whole sequence.
 
 Interpret mode (`kernels/runtime.py`) accepts any shape and never calls
-these — CPU CI runs the small test geometries there.
+the contract — CPU CI runs the small test geometries there.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax.numpy as jnp
 
-LANE = 128      # TPU lane count: last block dim must be a multiple
-SUBLANE = 8     # fp32 sublane grain; dtype grain = 8 * (4 // itemsize)
+LANE = 128      # TPU lane count: the minor block dim's grain
+SUBLANE = 8     # the second-minor block dim's grain, any dtype
 
 
 def round_up(n: int, m: int) -> int:
@@ -42,109 +37,48 @@ def next_pow2(n: int) -> int:
     return p
 
 
-def default_kb_tile(block_size: int) -> int:
-    """Auto K/V streaming granularity for the kb-tiled training grids:
-    one lane-width slice, or the whole block when it is smaller (small
-    blocks mask-pad instead of splitting)."""
-    return min(block_size, LANE)
+def resolve_kb_tile(kb_tile: int, block_size: int) -> int:
+    """K/V streaming granularity of the kb-tiled training grids: ``0``
+    picks one lane-width slice, or the whole block when it is smaller
+    (small blocks mask-pad instead of splitting).  It must split the
+    key block evenly so the kb grid dim covers it exactly."""
+    kb_tile = min(kb_tile or LANE, block_size)
+    if block_size % kb_tile:
+        raise ValueError(
+            f"kb_tile={kb_tile} must evenly divide block_size={block_size} "
+            f"so the kb grid dimension covers the key block exactly")
+    return kb_tile
 
 
 def sublane(dtype) -> int:
-    """Sublane grain of the (sublane × 128) tile for ``dtype``: 8 for
-    fp32 (and any wider dtype), 16 for bf16, 32 for int8/fp8."""
+    """Rows of one packed (rows × 128) vreg tile for ``dtype``: 8 for
+    fp32 (and any wider dtype), 16 for bf16, 32 for int8/fp8.  Padding a
+    tile's row count to this avoids partial vregs; legality only needs
+    :data:`SUBLANE`."""
     return SUBLANE * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
-def _fail(kernel: str, problems: list) -> None:
-    raise ValueError(
-        f"compiled {kernel} kernel tiling contract violated: "
-        + "; ".join(problems)
-        + " — choose a conforming geometry or run interpret mode "
-          "(REPRO_PALLAS_INTERPRET=1)")
-
-
-def check_decode_tiling(page_size: int, head_dim: int, dtype) -> None:
-    """Compiled-mode tiling contract for the grouped decode grid: the
-    (ps, d) page block must decompose into whole (sublane, 128) tiles.
-    Raises with a remediation hint; interpret mode never calls this."""
-    sub = sublane(dtype)
-    if page_size % sub or head_dim % LANE:
+def check_blocks(kernel: str, names: Sequence[str], specs: Sequence,
+                 operands: Sequence) -> None:
+    """Mosaic's block rule for a compiled ``pallas_call``: ``specs[i]``
+    (a ``BlockSpec``) tiles ``operands[i]`` (anything with ``.shape``),
+    called ``names[i]`` in the error.  Raises one ``ValueError`` listing
+    every violating block."""
+    problems = []
+    for name, spec, x in zip(names, specs, operands, strict=True):
+        block, shape = tuple(spec.block_shape), tuple(x.shape)
+        for axis, grain in ((-2, SUBLANE), (-1, LANE)):
+            if len(block) < -axis:
+                continue
+            b, full = block[axis], shape[axis]
+            if b % grain and b != full:
+                problems.append(
+                    f"{name} block {block} over {shape}: dim {axis} = {b} "
+                    f"must be a multiple of {grain} or the full {full}")
+    if problems:
         raise ValueError(
-            f"compiled paged-decode kernel needs ({sub}, {LANE})-tileable "
-            f"pages for dtype {jnp.dtype(dtype).name}: page_size="
-            f"{page_size} must be a multiple of {sub} and head_dim="
-            f"{head_dim} a multiple of {LANE} (got page_size % {sub} == "
-            f"{page_size % sub}, head_dim % {LANE} == {head_dim % LANE}); "
-            f"choose a conforming pool geometry or run interpret mode "
-            f"(REPRO_PALLAS_INTERPRET=1)")
-
-
-def check_moba_tiling(block_size: int, kb_tile: int, q_tile: int,
-                      head_dim: int, dtype) -> None:
-    """Compiled-mode tiling contract for the kb-tiled training grids
-    (``moba_fwd`` / ``moba_bwd``): every VMEM block the grid streams —
-    the (q_tile, d) query tile, the (kb_tile, d) key-block tile, and the
-    (q_tile, kb_tile) score tile — must decompose into whole
-    (sublane, 128) tiles, and ``kb_tile`` must evenly split the key
-    block so the kb grid dimension covers it exactly."""
-    sub = sublane(dtype)
-    name = jnp.dtype(dtype).name
-    problems = []
-    if head_dim % LANE:
-        problems.append(
-            f"head_dim={head_dim} must be a multiple of {LANE} (the TPU "
-            f"lane count); got head_dim % {LANE} == {head_dim % LANE}")
-    if q_tile % sub:
-        problems.append(
-            f"q_tile={q_tile} must be a multiple of the {name} sublane "
-            f"grain {sub}; got q_tile % {sub} == {q_tile % sub}")
-    if kb_tile % sub:
-        problems.append(
-            f"kb_tile={kb_tile} must be a multiple of the {name} sublane "
-            f"grain {sub}; got kb_tile % {sub} == {kb_tile % sub}")
-    if kb_tile % LANE and kb_tile != block_size:
-        problems.append(
-            f"kb_tile={kb_tile} is the lane dim of the (q_tile, kb_tile) "
-            f"score tile and must be a multiple of {LANE} when it splits "
-            f"the key block (kb_tile == block_size is exempt: small-block "
-            f"configs mask-pad instead); got kb_tile % {LANE} == "
-            f"{kb_tile % LANE}")
-    if block_size % kb_tile:
-        problems.append(
-            f"kb_tile={kb_tile} must evenly divide block_size="
-            f"{block_size} so the kb grid dimension covers the key block "
-            f"exactly; got block_size % kb_tile == "
-            f"{block_size % kb_tile}")
-    if problems:
-        _fail("moba fwd/bwd", problems)
-
-
-def check_topk_tiling(cent_tile: int, q_tile: int, head_dim: int,
-                      dtype) -> None:
-    """Compiled-mode tiling contract for the grouped Flash-TopK grid:
-    the (cent_tile, d) centroid block and the (G·q_tile, cent_tile)
-    score tile must decompose into whole (sublane, 128) tiles, and
-    ``cent_tile`` must be a power of two (the tile-local bitonic
-    tournament folds candidate lanes in halves)."""
-    sub = sublane(dtype)
-    name = jnp.dtype(dtype).name
-    problems = []
-    if head_dim % LANE:
-        problems.append(
-            f"head_dim={head_dim} must be a multiple of {LANE} (the TPU "
-            f"lane count); got head_dim % {LANE} == {head_dim % LANE}")
-    if q_tile % sub:
-        problems.append(
-            f"q_tile={q_tile} must be a multiple of the {name} sublane "
-            f"grain {sub}; got q_tile % {sub} == {q_tile % sub}")
-    if cent_tile % LANE:
-        problems.append(
-            f"cent_tile={cent_tile} is the lane dim of the score tile "
-            f"and must be a multiple of {LANE}; got cent_tile % {LANE} "
-            f"== {cent_tile % LANE}")
-    if cent_tile & (cent_tile - 1):
-        problems.append(
-            f"cent_tile={cent_tile} must be a power of two (the bitonic "
-            f"tournament folds candidate lanes in halves)")
-    if problems:
-        _fail("flash_topk", problems)
+            f"compiled {kernel} kernel block contract violated: "
+            + "; ".join(problems)
+            + " — choose a conforming geometry (q_tile a multiple of "
+              f"{SUBLANE} or the whole sequence) or run interpret mode "
+              "(REPRO_PALLAS_INTERPRET=1)")

@@ -8,13 +8,13 @@ from __future__ import annotations
 
 
 from repro.configs.base import MeshConfig
-from repro.distributed.sharding import make_compat_mesh
+from repro.distributed.sharding import auto_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def mesh_config(*, multi_pod: bool = False) -> MeshConfig:

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro import configs
 from repro.launch import steps as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.engine import Engine, EngineConfig, engine_supported
 from repro.serving.scheduler import ServingError
@@ -441,6 +442,7 @@ def main():
                     help=argparse.SUPPRESS)   # removed: structured error
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     backend = args.attn_backend or "reference"
     try:
         if args.moba_impl is not None:

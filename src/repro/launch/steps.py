@@ -198,7 +198,6 @@ def _shard_over_data(fn, mesh, n_host_args: int):
     replicated (spec ``P()``); the attention math inside is mesh-free
     (``Capabilities.sharded``), so no collective crosses shards — decode
     for S shards costs one dispatch instead of S."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def local(params, *args):
@@ -206,9 +205,9 @@ def _shard_over_data(fn, mesh, n_host_args: int):
                              *jax.tree.map(lambda x: x[0], list(args)))
         return tok[None], jax.tree.map(lambda x: x[None], new_caches)
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P(),) + (P("data"),) * n_host_args,
-                     out_specs=(P("data"), P("data")), check_rep=False)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(),) + (P("data"),) * n_host_args,
+                         out_specs=(P("data"), P("data")), check_vma=False)
 
 
 def make_sharded_paged_prefill_step(cfg: ModelConfig, mesh,

@@ -24,6 +24,7 @@ from repro.distributed.monitor import HeartbeatMonitor
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import steps as S
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adamw
 
@@ -151,6 +152,7 @@ def main():
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--key-conv", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.moba_impl is not None:
         ap.error(f"--moba-impl was removed; use "
                  f"--attn-backend {args.moba_impl} (same values)")

@@ -6,11 +6,14 @@ is exactly one routable block**: the per-page centroid cache doubles as
 the decode routing table, and reading it costs O(N/B·d) instead of the
 O(N·d) full-cache centroid recompute the old decode path paid per step.
 
-Layout: pools are token-major ``(num_pages, page_size, hkv, dh)`` so the
-flat ``(num_pages*page_size, hkv, dh)`` scatter/gather view used by the
-append paths is a free reshape, not a transpose-copy.  Invalid writes
-(padded rows, unassigned pages) are routed to the out-of-bounds slot
-``num_pages*page_size`` and dropped by the scatter — no dump page needed.
+Layout: pools are head-major ``(num_pages, hkv, page_size, dh)``, so one
+kv head's page is a contiguous ``(page_size, dh)`` tile — the block the
+Pallas decode kernel streams (a TPU block's last two dims must be whole
+(sublane, 128) tiles or the full array dims; DESIGN.md §5).  The append
+paths scatter a token's ``(hkv, dh)`` row with one advanced index
+``pool.at[page, :, offset]``.  Invalid writes (padded rows, unassigned
+pages) are routed to the out-of-bounds page ``num_pages`` and dropped by
+the scatter — no dump page needed.
 
 Sequences are described *outside* the pool by a block table: row i maps
 sequence i's logical page j to a physical page id (−1 = unassigned).
@@ -84,8 +87,8 @@ def init_page_pool(cfg: ModelConfig, num_pages: int, page_size: int,
     # only the page payload is quantized; key-conv ring buffers / tails
     # below keep the compute ``dtype`` (they feed the fp32 router)
     pg_dtype = dtype if kv_dtype == "fp32" else Q.payload_dtype(kv_dtype)
-    pool = {"pages_k": jnp.zeros((num_pages, page_size, hkv, dh), pg_dtype),
-            "pages_v": jnp.zeros((num_pages, page_size, hkv, dh), pg_dtype)}
+    pool = {"pages_k": jnp.zeros((num_pages, hkv, page_size, dh), pg_dtype),
+            "pages_v": jnp.zeros((num_pages, hkv, page_size, dh), pg_dtype)}
     if kv_dtype != "fp32":
         pool["scales_k"] = jnp.ones((num_pages, hkv), jnp.float32)
         pool["scales_v"] = jnp.ones((num_pages, hkv), jnp.float32)
@@ -141,28 +144,28 @@ def paged_append_decode(cache: Dict, block_table: jax.Array,
     routing state is bitwise identical across ``kv_dtype`` modes.
     """
     pk, pv = cache["pages_k"], cache["pages_v"]
-    num_pages, ps, hkv, dh = pk.shape
+    num_pages, hkv, ps, dh = pk.shape
     page_idx = kv_len // ps
     off = kv_len % ps
     phys = jnp.take_along_axis(block_table, page_idx[:, None], axis=1)[:, 0]
     ok = active & (phys >= 0)
+    pidx = jnp.where(ok, phys, num_pages)                   # OOB = dropped
     tok_k = k_new[:, :, 0]                                   # (B,hkv,dh)
     tok_v = v_new[:, :, 0]
     if "scales_k" in cache:
         kv_dt = Q.kv_dtype_of(pk.dtype)
         ph = jnp.maximum(phys, 0)
-        pidx = jnp.where(ok, phys, num_pages)
-        onehot = jnp.arange(ps)[None, :] == off[:, None]     # (B,ps)
-        vmask = jnp.arange(ps)[None, :] <= off[:, None]      # valid incl new
+        onehot = (jnp.arange(ps)[None, :] == off[:, None])[:, None, :, None]
+        vmask = (jnp.arange(ps)[None, :] <= off[:, None])[:, None, :, None]
 
         def requant(pool, scales, tok):
             page = Q.dequantize(pool[ph],
-                                scales[ph][:, None, :, None])  # (B,ps,h,d)
-            page = jnp.where(onehot[:, :, None, None],
-                             tok.astype(jnp.float32)[:, None], page)
-            scale = Q.compute_scale(page, (1, 3), kv_dt,
-                                    where=vmask[:, :, None, None])  # (B,h)
-            payload = Q.quantize(page, scale[:, None, :, None], kv_dt)
+                                scales[ph][:, :, None, None])  # (B,h,ps,d)
+            page = jnp.where(onehot, tok.astype(jnp.float32)[:, :, None],
+                             page)
+            scale = Q.compute_scale(page, (2, 3), kv_dt,
+                                    where=vmask)             # (B,h)
+            payload = Q.quantize(page, scale[:, :, None, None], kv_dt)
             return (pool.at[pidx].set(payload, mode="drop"),
                     scales.at[pidx].set(scale, mode="drop"))
 
@@ -171,21 +174,17 @@ def paged_append_decode(cache: Dict, block_table: jax.Array,
         new = dict(cache, pages_k=new_pk, pages_v=new_pv,
                    scales_k=new_sk, scales_v=new_sv)
     else:
-        slot = jnp.where(ok, phys * ps + off, num_pages * ps)
-        flat_k = pk.reshape(num_pages * ps, hkv, dh)
-        flat_v = pv.reshape(num_pages * ps, hkv, dh)
-        flat_k = flat_k.at[slot].set(tok_k.astype(pk.dtype), mode="drop")
-        flat_v = flat_v.at[slot].set(tok_v.astype(pv.dtype), mode="drop")
         new = dict(cache,
-                   pages_k=flat_k.reshape(num_pages, ps, hkv, dh),
-                   pages_v=flat_v.reshape(num_pages, ps, hkv, dh))
+                   pages_k=pk.at[pidx, :, off].set(tok_k.astype(pk.dtype),
+                                                   mode="drop"),
+                   pages_v=pv.at[pidx, :, off].set(tok_v.astype(pv.dtype),
+                                                   mode="drop"))
     if "centroids" in cache:
         cents = cache["centroids"]                           # (P,hkv,dh) f32
         m = off.astype(jnp.float32)[:, None, None]           # tokens in page
         old = cents[jnp.maximum(phys, 0)]                    # (B,hkv,dh)
         upd = (old * m + tok_k.astype(jnp.float32)) / (m + 1.0)
-        new["centroids"] = cents.at[jnp.where(ok, phys, num_pages)].set(
-            upd, mode="drop")
+        new["centroids"] = cents.at[pidx].set(upd, mode="drop")
     return new
 
 
@@ -215,7 +214,7 @@ def paged_append_prefill(cache: Dict, block_table: jax.Array,
     sees quantization error in its centroid.
     """
     pk, pv = cache["pages_k"], cache["pages_v"]
-    num_pages, ps, hkv, dh = pk.shape
+    num_pages, hkv, ps, dh = pk.shape
     b, _, length, _ = k_new.shape
     npg = block_table.shape[1]
     if kv_len is None:
@@ -224,6 +223,7 @@ def paged_append_prefill(cache: Dict, block_table: jax.Array,
     logical = jnp.minimum(pos // ps, npg - 1)
     phys = jnp.take_along_axis(block_table, logical, axis=1)  # (B,L)
     valid = (jnp.arange(length)[None, :] < q_len[:, None]) & (phys >= 0)
+    off = (pos % ps).reshape(-1)
     vals_k = k_new.transpose(0, 2, 1, 3).reshape(b * length, hkv, dh)
     vals_v = v_new.transpose(0, 2, 1, 3).reshape(b * length, hkv, dh)
     post = q_len + kv_len                                    # (B,)
@@ -231,27 +231,25 @@ def paged_append_prefill(cache: Dict, block_table: jax.Array,
     cnt = jnp.clip(post[:, None] - page_start, 0, ps)
     touched = ((cnt > 0) & (block_table >= 0)
                & (page_start + ps > kv_len[:, None]))        # (B,npg)
-    wmask = jnp.arange(ps)[None, None, :] < cnt[..., None]   # (B,npg,ps)
+    wmask = (jnp.arange(ps)[None, None, :]
+             < cnt[..., None])[:, :, None, :, None]          # (B,npg,1,ps,1)
     idx = jnp.where(touched, block_table, num_pages).reshape(-1)
 
     if "scales_k" in cache:
         kv_dt = Q.kv_dtype_of(pk.dtype)
         tbl = jnp.maximum(block_table, 0)
-        stage_slot = jnp.where(
-            valid, (jnp.arange(b)[:, None] * npg + logical) * ps + pos % ps,
-            b * npg * ps).reshape(-1)
+        row = jnp.repeat(jnp.arange(b), length)
+        lpage = jnp.where(valid, logical, npg).reshape(-1)   # npg = dropped
 
         def stage_and_quant(pool, scales, vals):
             stage = Q.dequantize(pool[tbl],
-                                 scales[tbl][:, :, None, :, None])
-            stage = stage.reshape(b * npg * ps, hkv, dh).at[stage_slot].set(
-                vals.astype(jnp.float32), mode="drop")
-            stage = stage.reshape(b, npg, ps, hkv, dh)
-            scale = Q.compute_scale(stage, (2, 4), kv_dt,
-                                    where=wmask[:, :, :, None, None])
-            payload = Q.quantize(stage, scale[:, :, None, :, None], kv_dt)
+                                 scales[tbl][..., None, None])
+            stage = stage.at[row, lpage, :, off].set(
+                vals.astype(jnp.float32), mode="drop")       # (B,npg,h,ps,d)
+            scale = Q.compute_scale(stage, (3, 4), kv_dt, where=wmask)
+            payload = Q.quantize(stage, scale[..., None, None], kv_dt)
             return stage, (
-                pool.at[idx].set(payload.reshape(b * npg, ps, hkv, dh),
+                pool.at[idx].set(payload.reshape(b * npg, hkv, ps, dh),
                                  mode="drop"),
                 scales.at[idx].set(scale.reshape(b * npg, hkv),
                                    mode="drop"))
@@ -262,23 +260,19 @@ def paged_append_prefill(cache: Dict, block_table: jax.Array,
             pv, cache["scales_v"], vals_v)
         new = dict(cache, pages_k=new_pk, pages_v=new_pv,
                    scales_k=new_sk, scales_v=new_sv)
-        cent_src = stage_k                                   # (B,npg,ps,h,d)
+        cent_src = stage_k                                   # (B,npg,h,ps,d)
     else:
-        slot = jnp.where(valid, phys * ps + pos % ps,
-                         num_pages * ps).reshape(-1)
-        flat_k = pk.reshape(num_pages * ps, hkv, dh).at[slot].set(
-            vals_k.astype(pk.dtype), mode="drop")
-        flat_v = pv.reshape(num_pages * ps, hkv, dh).at[slot].set(
-            vals_v.astype(pv.dtype), mode="drop")
-        new_pk = flat_k.reshape(num_pages, ps, hkv, dh)
-        new_pv = flat_v.reshape(num_pages, ps, hkv, dh)
+        page = jnp.where(valid, phys, num_pages).reshape(-1)  # OOB = dropped
+        new_pk = pk.at[page, :, off].set(vals_k.astype(pk.dtype),
+                                         mode="drop")
+        new_pv = pv.at[page, :, off].set(vals_v.astype(pv.dtype),
+                                         mode="drop")
         new = dict(cache, pages_k=new_pk, pages_v=new_pv)
-        cent_src = new_pk[jnp.maximum(block_table, 0)]       # (B,npg,ps,h,d)
+        cent_src = new_pk[jnp.maximum(block_table, 0)]       # (B,npg,h,ps,d)
     if "centroids" in cache:
-        sums = (cent_src.astype(jnp.float32)
-                * wmask[..., None, None]).sum(axis=2)        # (B,npg,h,d)
+        sums = (cent_src.astype(jnp.float32) * wmask).sum(axis=3)
         cent = sums / jnp.maximum(cnt, 1)[..., None, None].astype(
-            jnp.float32)
+            jnp.float32)                                     # (B,npg,h,d)
         new["centroids"] = cache["centroids"].at[idx].set(
             cent.reshape(b * npg, hkv, dh), mode="drop")
     return new
@@ -294,15 +288,15 @@ def paged_gather_kv(cache: Dict, block_table: jax.Array
     scale), so every densify consumer is dtype-oblivious.
     """
     pk, pv = cache["pages_k"], cache["pages_v"]
-    num_pages, ps, hkv, dh = pk.shape
+    num_pages, hkv, ps, dh = pk.shape
     b, npg = block_table.shape
     tbl = jnp.maximum(block_table, 0)
 
     def densify(pool, scales=None):
-        g = pool[tbl]                                        # (B,npg,ps,h,d)
+        g = pool[tbl]                                        # (B,npg,h,ps,d)
         if scales is not None:
-            g = Q.dequantize(g, scales[tbl][:, :, None, :, None])
-        return g.transpose(0, 3, 1, 2, 4).reshape(b, hkv, npg * ps, dh)
+            g = Q.dequantize(g, scales[tbl][..., None, None])
+        return g.transpose(0, 2, 1, 3, 4).reshape(b, hkv, npg * ps, dh)
 
     return (densify(pk, cache.get("scales_k")),
             densify(pv, cache.get("scales_v")))
@@ -327,7 +321,7 @@ def swa_windowed_decode_attention(q: jax.Array, cache: Dict,
                                       _grouped_scores)
 
     pk, pv = cache["pages_k"], cache["pages_v"]
-    num_pages, ps, hkv, dh = pk.shape
+    num_pages, hkv, ps, dh = pk.shape
     b, npg = block_table.shape
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
@@ -339,12 +333,12 @@ def swa_windowed_decode_attention(q: jax.Array, cache: Dict,
                                jnp.minimum(logical, npg - 1), axis=1)
     ok = (logical < npg) & (phys >= 0)                       # (B,wpg)
     tbl = jnp.maximum(phys, 0)
-    kg, vg = pk[tbl], pv[tbl]                                # (B,wpg,ps,h,d)
+    kg, vg = pk[tbl], pv[tbl]                                # (B,wpg,h,ps,d)
     if "scales_k" in cache:
-        kg = Q.dequantize(kg, cache["scales_k"][tbl][:, :, None, :, None])
-        vg = Q.dequantize(vg, cache["scales_v"][tbl][:, :, None, :, None])
-    kg = kg.transpose(0, 3, 1, 2, 4).reshape(b, hkv, wpg * ps, dh)
-    vg = vg.transpose(0, 3, 1, 2, 4).reshape(b, hkv, wpg * ps, dh)
+        kg = Q.dequantize(kg, cache["scales_k"][tbl][..., None, None])
+        vg = Q.dequantize(vg, cache["scales_v"][tbl][..., None, None])
+    kg = kg.transpose(0, 2, 1, 3, 4).reshape(b, hkv, wpg * ps, dh)
+    vg = vg.transpose(0, 2, 1, 3, 4).reshape(b, hkv, wpg * ps, dh)
     kpos = (logical[:, :, None] * ps
             + jnp.arange(ps)[None, None, :]).reshape(b, wpg * ps)
     mask = (jnp.repeat(ok, ps, axis=1)
@@ -515,7 +509,7 @@ def update_key_conv_tails(cache: Dict, block_table: jax.Array,
     strips it)."""
     tails = cache["key_conv_tails"]           # (P, hkv, depth, dh)
     num_pages, hkv, depth, dh = tails.shape
-    ps = cache["pages_k"].shape[1]
+    ps = cache["pages_k"].shape[2]
     b, _, length, _ = k_raw.shape
     npg = block_table.shape[1]
     pos = kv_len[:, None] + jnp.arange(length)               # (B,L) abs

@@ -109,7 +109,7 @@ class ShardedEngine:
                     f"n_shards={n_shards} exceeds the {len(jax.devices())}"
                     f" visible devices; simulate with XLA_FLAGS="
                     f"--xla_force_host_platform_device_count=N")
-            mesh = shmod.make_compat_mesh((n_shards,), ("data",))
+            mesh = shmod.auto_mesh((n_shards,), ("data",))
         if "data" not in mesh.axis_names:
             raise ServingError(
                 f"sharded engine needs a 'data' mesh axis, got "
@@ -545,7 +545,7 @@ class ShardedEngine:
         the ``sp`` backend.  ShardingConfig turns every other constraint
         off: only the MoBA KV cache is sequence-sharded."""
         if self._cp_mesh is None:
-            self._cp_mesh = shmod.make_compat_mesh(
+            self._cp_mesh = shmod.auto_mesh(
                 (1, self.n_shards), ("data", "model"))
             self._cp_prefill = jax.jit(
                 S.make_prefill_step(self.cfg, backend="sp"),

@@ -9,7 +9,7 @@ geometries — the SWA window-bounded page gather vs densify,
 admission-time UnsupportedFeatureError, preemption-replay equality
 through the engine on the flash backend, and the interpret/compiled
 lowering toggle (env var, registry attribute, ``flash:compiled`` spec,
-and the compiled-mode tiling contract)."""
+and the compiled-mode block contract)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,9 +43,9 @@ def _build_paged(rng, kv_lens, *, hkv=2, d=16, ps=16, npg=8, num_pages=32):
             table[i, j] = free.pop()
     table = jnp.asarray(table)
     cache = {
-        "pages_k": jnp.asarray(rng.normal(size=(num_pages, ps, hkv, d)),
+        "pages_k": jnp.asarray(rng.normal(size=(num_pages, hkv, ps, d)),
                                jnp.float32),
-        "pages_v": jnp.asarray(rng.normal(size=(num_pages, ps, hkv, d)),
+        "pages_v": jnp.asarray(rng.normal(size=(num_pages, hkv, ps, d)),
                                jnp.float32),
         "centroids": jnp.zeros((num_pages, hkv, d), jnp.float32)}
     cache = PC.paged_append_prefill(cache, table, jnp.asarray(kv_lens),
@@ -262,65 +262,93 @@ def test_resolve_interpret_precedence(monkeypatch):
         KR.resolve_interpret(None)
 
 
-def test_compiled_mode_tiling_asserts():
-    """The grouped grid's compiled-mode tiling contract: non-conforming
-    page_size / head_dim raise a shaped error *before* any pallas_call
-    (so a TPU host misconfiguration fails loudly, not inside Mosaic)."""
-    with pytest.raises(ValueError, match="multiple of 8"):
-        MD.check_decode_tiling(12, 128, jnp.float32)
-    with pytest.raises(ValueError, match="multiple of 128"):
-        MD.check_decode_tiling(16, 64, jnp.float32)
-    with pytest.raises(ValueError, match="multiple of 16"):
-        MD.check_decode_tiling(8, 128, jnp.bfloat16)    # bf16 sublane=16
-    MD.check_decode_tiling(8, 128, jnp.float32)         # conforming: ok
-    # end-to-end: a compiled request on a non-tileable pool raises
+def _spec(*block):
+    from jax.experimental import pallas as pl
+    return pl.BlockSpec(block, lambda *i: (0,) * len(block))
+
+
+def _shape(*dims):
+    return jax.ShapeDtypeStruct(dims, jnp.float32)
+
+
+def test_compiled_mode_tiling_asserts(monkeypatch):
+    """The compiled-mode block contract (``kernels/tiling.check_blocks``)
+    is Mosaic's rule: each of a block's last two dims is a multiple of
+    (8, 128) — for every dtype — or the full array dim; a violation
+    raises a shaped error naming the operand before any pallas_call.
+    The paged-decode blocks are whole (ps, d) pages of the head-major
+    pool, so even the odd-tiles geometry (ps=12, d=24) passes it and a
+    compiled request reaches ``pallas_call`` with interpret=False."""
+    from repro.kernels import tiling as TL
+    with pytest.raises(ValueError, match=r"q block \(1, 12, 128\) over "
+                       r"\(4, 1020, 128\): dim -2 = 12 must be a multiple "
+                       r"of 8 or the full 1020"):
+        TL.check_blocks("k", ["q"], [_spec(1, 12, 128)],
+                        [_shape(4, 1020, 128)])
+    with pytest.raises(ValueError, match="dim -1 = 64 must be a multiple "
+                                         "of 128 or the full 256"):
+        TL.check_blocks("k", ["x"], [_spec(1, 8, 64)], [_shape(2, 16, 256)])
+    with pytest.raises(ValueError, match="x block.*; y block"):
+        TL.check_blocks("k", ["x", "y"], [_spec(1, 4, 128), _spec(1, 8, 8)],
+                        [_shape(2, 16, 128), _shape(2, 8, 16)])
+    TL.check_blocks("k", ["x"], [_spec(1, 12, 24)], [_shape(3, 12, 24)])
+    TL.check_blocks("k", ["x"], [_spec(1, 1, 12, 24)],
+                    [_shape(9, 2, 12, 24)])               # a whole page
+    TL.check_blocks("k", ["x"], [_spec(1, 8, 128)],
+                    [jax.ShapeDtypeStruct((2, 64, 256), jnp.int8)])
+
+    seen = []
+    real = MD.pl.pallas_call
+
+    def spy(*a, **kw):
+        seen.append(kw.get("interpret"))
+        kw["interpret"] = True
+        return real(*a, **kw)
+
+    monkeypatch.setattr(MD.pl, "pallas_call", spy)
     args, _, cfg = _decode_case(GEOMETRIES["odd-tiles"])
-    with pytest.raises(ValueError, match="tileable"):
-        moba_paged_decode_pallas(*args, interpret=False, grid="grouped")
+    for grid in ("grouped", "flat"):
+        moba_paged_decode_pallas(*args, interpret=False, grid=grid)
+    assert seen == [False, False]
 
 
 def test_compiled_mode_moba_tiling_contract():
-    """check_moba_tiling / check_topk_tiling (kernels/tiling.py) raise
-    shaped errors naming the violating dimension — and the fwd/topk
-    wrappers invoke them before any compiled pallas_call."""
-    from repro.kernels import tiling as TL
+    """The fwd/bwd/topk wrappers hand their block specs to the contract
+    before a compiled pallas_call: a q_tile that is neither a multiple of
+    8 nor the whole sequence raises the shaped error naming the kernel
+    and operand; a kb_tile that does not split the key block raises in
+    either mode."""
     from repro.kernels.flash_topk import flash_topk
+    from repro.kernels.moba_bwd import moba_bwd
     from repro.kernels.moba_fwd import moba_fwd
 
-    with pytest.raises(ValueError, match="head_dim=64 must be a multiple"):
-        TL.check_moba_tiling(128, 128, 128, 64, jnp.float32)
-    with pytest.raises(ValueError, match="q_tile=12 must be a multiple"):
-        TL.check_moba_tiling(128, 128, 12, 128, jnp.float32)
-    with pytest.raises(ValueError, match="kb_tile=8 .*bfloat16 sublane"):
-        TL.check_moba_tiling(128, 8, 16, 128, jnp.bfloat16)
-    with pytest.raises(ValueError, match="evenly divide block_size"):
-        TL.check_moba_tiling(96, 64, 128, 128, jnp.float32)
-    # kb_tile == block_size is exempt from the %128 lane rule (small
-    # blocks mask-pad); a proper sub-tile is not
-    TL.check_moba_tiling(32, 32, 128, 128, jnp.float32)
-    with pytest.raises(ValueError, match="kb_tile=64 is the lane dim"):
-        TL.check_moba_tiling(256, 64, 128, 128, jnp.float32)
-    with pytest.raises(ValueError, match="cent_tile=96 is the lane dim"):
-        TL.check_topk_tiling(96, 128, 128, jnp.float32)
-    with pytest.raises(ValueError, match="power of two"):
-        TL.check_topk_tiling(384, 128, 128, jnp.float32)
-    TL.check_topk_tiling(128, 128, 128, jnp.float32)
-
-    # wrapper seam: a compiled request on non-tileable shapes raises the
-    # shaped contract error before any pallas_call is attempted
-    tb = jnp.zeros((2, 1), jnp.int32)
-    qs = jnp.zeros((2, 32, 16), jnp.float32)
-    qp = jnp.zeros((2, 32), jnp.int32)
+    tb = jnp.zeros((2, 2), jnp.int32)
+    qs = jnp.zeros((2, 24, 16), jnp.float32)
+    qp = jnp.zeros((2, 24), jnp.int32)
     kb = jnp.zeros((1, 4, 16, 16), jnp.float32)
-    with pytest.raises(ValueError, match="moba fwd/bwd"):
-        moba_fwd(tb, qs, qp, kb, kb, scale=0.25, block_size=16,
-                 n_tokens=64, num_q_heads=2, group=2, q_tile=32,
-                 interpret=False)
-    q = jnp.zeros((2, 128, 16), jnp.float32)
+    kw = dict(scale=0.25, block_size=16, n_tokens=64, num_q_heads=2,
+              group=2, q_tile=12, interpret=False)
+    for grid in ("grouped", "flat"):
+        with pytest.raises(ValueError, match=r"compiled moba_fwd kernel "
+                           r"block contract.*q block \(1, 12, 16\)"):
+            moba_fwd(tb, qs, qp, kb, kb, grid=grid, **kw)
+        with pytest.raises(ValueError, match=r"compiled moba_bwd kernel "
+                           r"block contract.*q_pos block \(1, 12, 1\)"):
+            moba_bwd(tb, qs, qp, qs, qp.astype(jnp.float32),
+                     qp.astype(jnp.float32), kb, kb, grid=grid, **kw)
+    with pytest.raises(ValueError, match="evenly divide block_size=16"):
+        moba_fwd(tb, qs, qp, kb, kb, kb_tile=12,
+                 **dict(kw, interpret=True))
+
+    q = jnp.zeros((2, 36, 16), jnp.float32)
     cents = jnp.zeros((1, 4, 16), jnp.float32)
-    with pytest.raises(ValueError, match="flash_topk"):
-        flash_topk(q, cents, 2, 16, group=2, num_q_heads=2,
-                   cent_tile=128, interpret=False)
+    with pytest.raises(ValueError, match=r"compiled flash_topk kernel "
+                       r"block contract.*q block \(2, 12, 16\)"):
+        flash_topk(q, cents, 2, 16, group=2, num_q_heads=2, q_tile=12,
+                   interpret=False)
+    with pytest.raises(ValueError, match="power-of-two cent_tile"):
+        flash_topk(q, cents, 2, 16, group=2, num_q_heads=2, q_tile=12,
+                   cent_tile=96, interpret=False)
 
 
 def test_registry_interpret_toggle_reaches_pallas_call(monkeypatch):

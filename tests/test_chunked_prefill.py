@@ -44,8 +44,8 @@ def test_chunked_append_pool_and_routing_match_oneshot():
     table = jnp.asarray(np.arange(npg, dtype=np.int32)[None])
 
     def fresh():
-        return {"pages_k": jnp.zeros((num_pages, ps, hkv, d), jnp.float32),
-                "pages_v": jnp.zeros((num_pages, ps, hkv, d), jnp.float32),
+        return {"pages_k": jnp.zeros((num_pages, hkv, ps, d), jnp.float32),
+                "pages_v": jnp.zeros((num_pages, hkv, ps, d), jnp.float32),
                 "centroids": jnp.zeros((num_pages, hkv, d), jnp.float32)}
 
     one = PC.paged_append_prefill(fresh(), table, jnp.asarray([n]), kc, vc)

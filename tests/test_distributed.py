@@ -31,7 +31,7 @@ def test_sp_attention_matches_reference():
     from repro.core import moba
     from repro.distributed import sharding as shmod
     from repro.distributed.moba_sp import moba_attention_sp
-    mesh = shmod.make_compat_mesh((2, 4), ("data", "model"))
+    mesh = shmod.auto_mesh((2, 4), ("data", "model"))
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (2, 4, 128, 16))
     k = jax.random.normal(ks[1], (2, 2, 128, 16))
@@ -54,7 +54,7 @@ def test_cp_decode_matches_reference():
     from repro.core import moba
     from repro.distributed import sharding as shmod
     from repro.distributed.moba_sp import moba_decode_cp
-    mesh = shmod.make_compat_mesh((2, 4), ("data", "model"))
+    mesh = shmod.auto_mesh((2, 4), ("data", "model"))
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (2, 4, 1, 16))
     kc = jax.random.normal(ks[1], (2, 2, 256, 16))
@@ -75,10 +75,9 @@ def test_compressed_psum_all_shards_agree():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.optim import compression
-    from repro.distributed.sharding import make_compat_mesh
-    mesh = make_compat_mesh((8,), ("data",))
+    from repro.distributed.sharding import auto_mesh
+    mesh = auto_mesh((8,), ("data",))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
     def body(g_local, r_local):
@@ -86,10 +85,10 @@ def test_compressed_psum_all_shards_agree():
             {"g": g_local}, ("data",), {"g": r_local})
         return tree["g"], res["g"]
 
-    out, res = shard_map(body, mesh=mesh,
-                         in_specs=(P("data"), P("data")),
-                         out_specs=(P("data"), P("data")),
-                         check_rep=False)(g, jnp.zeros((8, 64)))
+    out, res = jax.shard_map(body, mesh=mesh,
+                             in_specs=(P("data"), P("data")),
+                             out_specs=(P("data"), P("data")),
+                             check_vma=False)(g, jnp.zeros((8, 64)))
     true_mean = jnp.mean(g, axis=0)
     for shard in np.asarray(out).reshape(8, 1, 64):
         np.testing.assert_allclose(shard[0], np.asarray(true_mean),
@@ -102,8 +101,8 @@ def test_pipeline_forward():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.distributed.pipeline import pipeline_forward
-    from repro.distributed.sharding import make_compat_mesh
-    mesh = make_compat_mesh((4,), ("model",))
+    from repro.distributed.sharding import auto_mesh
+    mesh = auto_mesh((4,), ("model",))
     # 4 stages of y = tanh(x @ w_s)
     ws = jax.random.normal(jax.random.PRNGKey(0), (4, 16, 16)) * 0.5
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16))

@@ -18,8 +18,8 @@ measured against the fp32 paths they shadow —
   * engine-level greedy decode on NIAH prompts agrees token-for-token
     across backends at each quantized dtype (xla vs flash on the same
     int8/fp8 pool), the serving analogue of the kernel-level gate;
-  * the compiled-mode tiling contract knows byte-wide payloads pack 32
-    sublanes (vs 8 for fp32).
+  * the compiled-mode block contract holds byte-wide payload blocks to
+    the same (8, 128)-or-full rule as fp32.
 """
 import jax
 import jax.numpy as jnp
@@ -276,14 +276,22 @@ def test_niah_needle_routing_parity_with_fp32(kv_dtype):
 
 # --------------------------------------------------- tiling + pool layout
 def test_tiling_contract_knows_byte_dtypes():
-    """Byte-wide payloads pack 32 rows per sublane tile: page_size must
-    be a multiple of 32 in compiled mode, vs 8 for fp32."""
-    for dt in (jnp.int8, jnp.float8_e4m3fn):
-        MD.check_decode_tiling(32, 128, dt)
-        MD.check_decode_tiling(64, 256, dt)
-        with pytest.raises(ValueError, match="multiple of 32"):
-            MD.check_decode_tiling(16, 128, dt)
-    MD.check_decode_tiling(16, 128, jnp.float32)  # fp32 grain unchanged
+    """Mosaic's block rule does not scale with the dtype: a byte-wide
+    payload block needs the same (8, 128)-or-full dims as fp32.  A page
+    of a head-major int8/fp8 pool is a whole (ps, d) block at any page
+    size, so the contract passes it; a q tile of 8 rows passes too."""
+    from jax.experimental import pallas as pl
+    from repro.kernels.tiling import check_blocks
+    page = pl.BlockSpec((1, 1, 16, 128), lambda *i: (0, 0, 0, 0))
+    rows = pl.BlockSpec((1, 8, 128), lambda *i: (0, 0, 0))
+    for dt in (jnp.int8, jnp.float8_e4m3fn, jnp.float32):
+        check_blocks("paged decode", ["pages_k", "q"], [page, rows],
+                     [jax.ShapeDtypeStruct((64, 8, 16, 128), dt),
+                      jax.ShapeDtypeStruct((4, 64, 128), dt)])
+        with pytest.raises(ValueError, match="multiple of 8"):
+            check_blocks("paged decode", ["q"],
+                         [pl.BlockSpec((1, 4, 128), lambda *i: (0, 0, 0))],
+                         [jax.ShapeDtypeStruct((4, 64, 128), dt)])
 
 
 def test_pool_layout_and_fp32_passthrough():

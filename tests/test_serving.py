@@ -27,8 +27,8 @@ def _build_paged(rng, kv_lens, *, hkv=2, d=16, ps=16, npg=8, num_pages=32):
         for j in range(-(-n // ps)):
             table[i, j] = free.pop()
     table = jnp.asarray(table)
-    cache = {"pages_k": jnp.zeros((num_pages, ps, hkv, d), jnp.float32),
-             "pages_v": jnp.zeros((num_pages, ps, hkv, d), jnp.float32),
+    cache = {"pages_k": jnp.zeros((num_pages, hkv, ps, d), jnp.float32),
+             "pages_v": jnp.zeros((num_pages, hkv, ps, d), jnp.float32),
              "centroids": jnp.zeros((num_pages, hkv, d), jnp.float32)}
     cache = PC.paged_append_prefill(cache, table, jnp.asarray(kv_lens),
                                     kc, vc)

@@ -287,7 +287,7 @@ def test_cp_decode_awkward_length_falls_back_gracefully():
     from repro.core import moba
     from repro.distributed import sharding as shmod
     from repro.distributed.moba_sp import moba_decode_cp
-    mesh = shmod.make_compat_mesh((2, 4), ("data", "model"))
+    mesh = shmod.auto_mesh((2, 4), ("data", "model"))
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (2, 4, 1, 16))
     kc = jax.random.normal(ks[1], (2, 2, 208, 16))   # 208 % (4*16) != 0
