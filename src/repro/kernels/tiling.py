@@ -2,8 +2,10 @@
 Pallas kernels.
 
 Mosaic, the TPU's kernel compiler, accepts a VMEM block only if each of
-its last two dims is a multiple of (8, 128) — for every dtype — or equals
-that dim of the array it tiles; anything else fails deep inside the
+its last two dims is a multiple of (8, 128) — for every dtype, packed
+ones included (``tests/test_tpu_compile.py`` lowers 8-row blocks in
+bf16, int8 and fp8) — or equals that dim of the array it tiles;
+anything else fails deep inside the
 lowering with an unshaped error.  Every kernel wrapper therefore hands
 its block specs to :func:`check_blocks` before a compiled
 ``pallas_call``, which raises a shaped ``ValueError`` naming the operand,

@@ -108,7 +108,7 @@ def train(arch: str, steps: int = 50, batch: int = 8, seq: int = 512,
         losses.append(loss)
         monitor.beat(step)
         if on_step:
-            on_step(step, loss)
+            on_step(step, {k: float(x) for k, x in metrics.items()})
         if step % log_every == 0 or step == steps - 1:
             dt = time.time() - t0
             print(f"step {step:5d} loss {loss:8.4f} "

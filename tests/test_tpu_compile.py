@@ -15,6 +15,7 @@ test worker imports this file.
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental import pallas as pl
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import MoBAConfig
@@ -22,6 +23,7 @@ from repro.kernels import ops
 from repro.kernels.centroids import block_centroids_kernel
 from repro.kernels.flash_topk import flash_topk
 from repro.kernels.moba_decode import moba_paged_decode_pallas
+from repro.kernels.tiling import LANE, check_blocks
 
 H, HKV, D, BS, TOP_K = 16, 8, 128, 128, 8     # qwen3-0.6b (MoBA)
 N = 2048                                      # sequence length
@@ -92,6 +94,35 @@ def test_flash_moba_grad_lowers(one_chip, grid):
         return out.astype(jnp.float32).sum()
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), QKV, one_chip)
+
+
+@pytest.mark.parametrize("grid", ["grouped", "flat"])
+def test_flash_moba_q_tile_8_lowers(one_chip, grid):
+    """The contract's edge in bf16: q and do blocks of 8 rows over a
+    2048-row sequence, neither bf16's 16-row tile nor the full dim."""
+    def loss(q, k, v):
+        out = ops.flash_moba(q, k, v, MOBA, grid=grid, q_tile=8,
+                             interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), QKV, one_chip)
+
+
+@pytest.mark.parametrize("dtype", [jnp.int8, jnp.float8_e4m3fn])
+def test_eight_row_block_lowers(one_chip, dtype):
+    """The contract's edge in 1-byte dtypes: a copy through (8, 128)
+    blocks of a (64, 128) array passes ``check_blocks`` and lowers,
+    though a packed tile of these dtypes holds 32 rows."""
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    spec = pl.BlockSpec((8, LANE), lambda i: (i, 0))
+    shape = jax.ShapeDtypeStruct((64, LANE), dtype)
+    check_blocks("copy", ("x", "out"), (spec, spec), (shape, shape))
+    _compile(lambda x: pl.pallas_call(
+        copy, grid=(8,), in_specs=[spec], out_specs=spec,
+        out_shape=shape, interpret=False)(x), [((64, LANE), dtype)],
+        one_chip)
 
 
 @pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
