@@ -60,6 +60,6 @@ def block_centroids_kernel(k: jax.Array, block_size: int,
         functools.partial(_centroid_kernel, block_size=block_size,
                           n_tokens=n, cb=cb),
         grid=(h, nb_p // cb), in_specs=[in_spec], out_specs=out_spec,
-        out_shape=out_shape, interpret=interpret,
+        out_shape=out_shape, interpret=interpret, name="centroids",
     )(k)
     return out[:, :nb]

@@ -267,5 +267,5 @@ def flash_topk(q: jax.Array, centroids: jax.Array, top_k: int,
         in_specs=in_specs, out_specs=out_spec, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((g_blk * q_tile, cent_tile), jnp.float32),
                         pltpu.VMEM((g_blk * q_tile, cent_tile), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret, name="flash_topk",
     )(q, centroids)

@@ -242,7 +242,7 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
             n_tokens=n_tokens, causal=causal)
         return pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret, name="moba_bwd",
         )(tile_block, q_sorted, q_pos, do_sorted, lse_sorted, delta_sorted,
           k_blocks, v_blocks)
 
@@ -298,6 +298,6 @@ def moba_bwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
         causal=causal)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="moba_bwd",
     )(tile_block, q_sorted, q_pos, do_sorted, lse_sorted, delta_sorted,
       k_t, v_t)

@@ -234,7 +234,7 @@ def _decode_grouped(q, pages_k, pages_v, block_table, kv_len, idx,
                                n_union=cap, scale=float(scale))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="moba_decode",
     )(phys_f, kvl_f, ksc_f, vsc_f, q_f, base_f, pages_k, pages_v)
     return out[:, :g].reshape(b, h, 1, d).astype(q.dtype)
 
@@ -350,7 +350,7 @@ def _decode_flat(q, pages_k, pages_v, block_table, kv_len, idx, sel_valid,
                                top_k=tk, scale=float(scale))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="moba_decode",
     )(phys_f, base_f, kvl_f, ksc_f, vsc_f, q_f, pages_k, pages_v)
     return out.reshape(b, h, 1, d).astype(q.dtype)
 

@@ -207,7 +207,7 @@ def moba_fwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
             n_tokens=n_tokens, causal=causal)
         o, m, l = pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret, name="moba_fwd",
         )(tile_block, q_sorted, q_pos, k_blocks, v_blocks)
         return o, m[..., 0], l[..., 0]
 
@@ -255,6 +255,6 @@ def moba_fwd(tile_block: jax.Array, q_sorted: jax.Array, q_pos: jax.Array,
         causal=causal)
     o, m, l = pl.pallas_call(
         kernel, grid_spec=grid_spec, out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, name="moba_fwd",
     )(tile_block, q_sorted, q_pos, k_t, v_t)
     return o, m[..., 0], l[..., 0]

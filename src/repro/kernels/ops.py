@@ -21,6 +21,12 @@ of the tiled grids (0 = auto).
 Routing is non-differentiable (hard top-k; matches MoBA training
 semantics) — gradients flow through attention only, which is what lets
 key convolution learn clustering (paper App. B.2).
+
+Each stage runs under a ``jax.named_scope`` that the compiled ops keep
+in their ``op_name`` metadata, by which a profile can attribute device
+time: ``moba_centroids``, ``moba_topk``, ``moba_layout``, ``moba_fwd``
+and ``moba_merge`` forward; ``moba_bwd_prep``, ``moba_bwd`` and
+``moba_bwd_reduce`` backward.
 """
 from __future__ import annotations
 
@@ -75,47 +81,51 @@ def _fwd_pipeline(q, k, v, meta: _Meta):
     tile = min(tile, nq)
     nq_p = round_up(nq, tile)
 
-    k_blocks, nb = _flatten_kv_blocks(k, bs)
-    v_blocks, _ = _flatten_kv_blocks(v, bs)
+    with jax.named_scope("moba_centroids"):
+        cents = block_centroids_kernel(
+            k.reshape(b * hkv, n, d), bs, interpret=meta.interpret)
 
-    cents = block_centroids_kernel(
-        k.reshape(b * hkv, n, d), bs, interpret=meta.interpret)
+    with jax.named_scope("moba_topk"):
+        qf = q.reshape(b * h, nq, d)
+        if nq_p != nq:
+            qf = jnp.pad(qf, ((0, 0), (0, nq_p - nq), (0, 0)))
+        q_pos_offset = n - nq
+        sel = flash_topk(qf, cents, tk, bs, group=g, num_q_heads=h,
+                         causal=meta.causal, q_pos_offset=q_pos_offset,
+                         q_tile=tile, grid=meta.grid,
+                         interpret=meta.interpret)  # (BH, Nq_p, k)
 
-    qf = q.reshape(b * h, nq, d)
-    if nq_p != nq:
-        qf = jnp.pad(qf, ((0, 0), (0, nq_p - nq), (0, 0)))
-    q_pos_offset = n - nq
-    sel = flash_topk(qf, cents, tk, bs, group=g, num_q_heads=h,
-                     causal=meta.causal, q_pos_offset=q_pos_offset,
-                     q_tile=tile, grid=meta.grid,
-                     interpret=meta.interpret)  # (BH, Nq_p, k)
-    if nq_p != nq:
-        # pad queries route to the sentinel block → q_pos = -1 slots the
-        # kernels mask out
-        row = jnp.arange(nq_p)[None, :, None]
-        sel = jnp.where(row < nq, sel, nb)
+    with jax.named_scope("moba_layout"):
+        k_blocks, nb = _flatten_kv_blocks(k, bs)
+        v_blocks, _ = _flatten_kv_blocks(v, bs)
+        if nq_p != nq:
+            # pad queries route to the sentinel block → q_pos = -1 slots
+            # the kernels mask out
+            row = jnp.arange(nq_p)[None, :, None]
+            sel = jnp.where(row < nq, sel, nb)
+        lay = _build_layouts(sel, nq_p, nb, tile)
+        qi = jnp.maximum(lay.q_index, 0)                      # (BH, L)
+        q_sorted = jnp.take_along_axis(qf, qi[..., None], axis=1)
+        q_pos = jnp.where(lay.q_index >= 0, qi + q_pos_offset, -1)
 
-    lay = _build_layouts(sel, nq_p, nb, tile)
-    qi = jnp.maximum(lay.q_index, 0)                          # (BH, L)
-    q_sorted = jnp.take_along_axis(qf, qi[..., None], axis=1)
-    q_pos = jnp.where(lay.q_index >= 0, qi + q_pos_offset, -1)
+    with jax.named_scope("moba_fwd"):
+        o_l, m_l, l_l = moba_fwd(
+            lay.tile_block, q_sorted, q_pos.astype(jnp.int32),
+            k_blocks, v_blocks, scale=meta.scale, block_size=bs,
+            n_tokens=n, num_q_heads=h, group=g, causal=meta.causal,
+            q_tile=tile, kb_tile=meta.kb_tile, grid=meta.grid,
+            interpret=meta.interpret)
 
-    o_l, m_l, l_l = moba_fwd(
-        lay.tile_block, q_sorted, q_pos.astype(jnp.int32),
-        k_blocks, v_blocks, scale=meta.scale, block_size=bs,
-        n_tokens=n, num_q_heads=h, group=g, causal=meta.causal,
-        q_tile=tile, kb_tile=meta.kb_tile, grid=meta.grid,
-        interpret=meta.interpret)
-
-    slots = lay.pair_slot.reshape(b * h, nq_p * tk)           # (BH, Nq_p*k)
-    o_parts = jnp.take_along_axis(o_l, slots[..., None], axis=1)
-    m_parts = jnp.take_along_axis(m_l, slots, axis=1)
-    l_parts = jnp.take_along_axis(l_l, slots, axis=1)
-    out, lse = kref.merge_partials(
-        o_parts.reshape(b * h, nq_p, tk, d),
-        m_parts.reshape(b * h, nq_p, tk),
-        l_parts.reshape(b * h, nq_p, tk))
-    return out[:, :nq], lse[:, :nq], lay, q_sorted, q_pos
+    with jax.named_scope("moba_merge"):
+        slots = lay.pair_slot.reshape(b * h, nq_p * tk)       # (BH, Nq_p*k)
+        o_parts = jnp.take_along_axis(o_l, slots[..., None], axis=1)
+        m_parts = jnp.take_along_axis(m_l, slots, axis=1)
+        l_parts = jnp.take_along_axis(l_l, slots, axis=1)
+        out, lse = kref.merge_partials(
+            o_parts.reshape(b * h, nq_p, tk, d),
+            m_parts.reshape(b * h, nq_p, tk),
+            l_parts.reshape(b * h, nq_p, tk))
+        return out[:, :nq], lse[:, :nq], lay, q_sorted, q_pos
 
 
 def _flash_moba_impl(q, k, v, meta: _Meta):
@@ -145,42 +155,44 @@ def _flash_moba_bwd(meta: _Meta, res, g_out):
     bs, tk, tile = meta.block_size, meta.top_k, min(meta.q_tile, nq)
     nq_p = pair_slot.shape[1]
 
-    k_blocks, nb = _flatten_kv_blocks(k, bs)
-    v_blocks, _ = _flatten_kv_blocks(v, bs)
+    with jax.named_scope("moba_bwd_prep"):
+        k_blocks, nb = _flatten_kv_blocks(k, bs)
+        v_blocks, _ = _flatten_kv_blocks(v, bs)
+        do = g_out.reshape(b * h, nq, d).astype(jnp.float32)
+        delta = jnp.sum(do * out, axis=-1)                    # (BH, Nq)
+        # scatter per-query tensors to the sorted layout (q_pos = -1 pad
+        # and sentinel slots gather row 0 but are masked in the kernel)
+        qi = jnp.maximum(q_pos - (n - nq), 0)                 # query index
+        do_sorted = jnp.take_along_axis(do, qi[..., None], axis=1)
+        lse_sorted = jnp.take_along_axis(lse, qi, axis=1)
+        delta_sorted = jnp.take_along_axis(delta, qi, axis=1)
 
-    do = g_out.reshape(b * h, nq, d).astype(jnp.float32)
-    delta = jnp.sum(do * out, axis=-1)                        # (BH, Nq)
+    with jax.named_scope("moba_bwd"):
+        dq_l, dk_bh, dv_bh = moba_bwd(
+            tile_block, q_sorted, q_pos, do_sorted, lse_sorted,
+            delta_sorted, k_blocks, v_blocks, scale=meta.scale,
+            block_size=bs, n_tokens=n, num_q_heads=h, group=g,
+            causal=meta.causal, q_tile=tile, kb_tile=meta.kb_tile,
+            grid=meta.grid, interpret=meta.interpret)
 
-    # scatter per-query tensors to the sorted layout (q_pos = -1 pad and
-    # sentinel slots gather row 0 but are masked inside the kernel)
-    qi = jnp.maximum(q_pos - (n - nq), 0)                     # query index
-    do_sorted = jnp.take_along_axis(do, qi[..., None], axis=1)
-    lse_sorted = jnp.take_along_axis(lse, qi, axis=1)
-    delta_sorted = jnp.take_along_axis(delta, qi, axis=1)
+    with jax.named_scope("moba_bwd_reduce"):
+        # dQ: gather per-pair contributions and sum over the k slots.
+        slots = pair_slot.reshape(b * h, nq_p * tk)
+        dq_pairs = jnp.take_along_axis(dq_l, slots[..., None], axis=1)
+        dq = dq_pairs.reshape(b * h, nq_p, tk, d).sum(axis=2)[:, :nq]
 
-    dq_l, dk_bh, dv_bh = moba_bwd(
-        tile_block, q_sorted, q_pos, do_sorted, lse_sorted, delta_sorted,
-        k_blocks, v_blocks, scale=meta.scale, block_size=bs, n_tokens=n,
-        num_q_heads=h, group=g, causal=meta.causal, q_tile=tile,
-        kb_tile=meta.kb_tile, grid=meta.grid, interpret=meta.interpret)
-
-    # dQ: gather per-pair contributions and sum over the k slots.
-    slots = pair_slot.reshape(b * h, nq_p * tk)
-    dq_pairs = jnp.take_along_axis(dq_l, slots[..., None], axis=1)
-    dq = dq_pairs.reshape(b * h, nq_p, tk, d).sum(axis=2)[:, :nq]
-
-    # dK/dV: zero unvisited blocks, reduce over the GQA group, un-block.
-    visited = (jax.nn.one_hot(tile_block, nb + 1, dtype=jnp.float32)
-               .sum(axis=1)[..., :nb] > 0)                    # (BH, nb)
-    dk_bh = dk_bh * visited[..., None, None]
-    dv_bh = dv_bh * visited[..., None, None]
-    dk = dk_bh.reshape(b, hkv, g, nb, bs, d).sum(axis=2)
-    dv = dv_bh.reshape(b, hkv, g, nb, bs, d).sum(axis=2)
-    dk = dk.reshape(b, hkv, nb * bs, d)[:, :, :n]
-    dv = dv.reshape(b, hkv, nb * bs, d)[:, :, :n]
-
-    return (dq.reshape(b, h, nq, d).astype(q.dtype),
-            dk.astype(k.dtype), dv.astype(v.dtype))
+        # dK/dV: zero unvisited blocks, reduce over the GQA group,
+        # un-block.
+        visited = (jax.nn.one_hot(tile_block, nb + 1, dtype=jnp.float32)
+                   .sum(axis=1)[..., :nb] > 0)                # (BH, nb)
+        dk_bh = dk_bh * visited[..., None, None]
+        dv_bh = dv_bh * visited[..., None, None]
+        dk = dk_bh.reshape(b, hkv, g, nb, bs, d).sum(axis=2)
+        dv = dv_bh.reshape(b, hkv, g, nb, bs, d).sum(axis=2)
+        dk = dk.reshape(b, hkv, nb * bs, d)[:, :, :n]
+        dv = dv.reshape(b, hkv, nb * bs, d)[:, :, :n]
+        return (dq.reshape(b, h, nq, d).astype(q.dtype),
+                dk.astype(k.dtype), dv.astype(v.dtype))
 
 
 _flash_moba.defvjp(_flash_moba_fwd, _flash_moba_bwd)

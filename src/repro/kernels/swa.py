@@ -114,5 +114,5 @@ def swa_attention(q: jax.Array, k: jax.Array, v: jax.Array, window: int,
         scratch_shapes=[pltpu.VMEM((q_tile, 1), jnp.float32),
                         pltpu.VMEM((q_tile, 1), jnp.float32),
                         pltpu.VMEM((q_tile, d), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret, name="swa",
     )(q, k, v)

@@ -24,7 +24,7 @@ from repro.distributed.monitor import HeartbeatMonitor
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.launch import steps as S
-from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.compile_cache import compile_counts, enable_compile_cache
 from repro.models import transformer as T
 from repro.optim import adamw
 
@@ -125,6 +125,7 @@ def train(arch: str, steps: int = 50, batch: int = 8, seq: int = 512,
         mgr.wait()
     if monitor.straggler_steps:
         print(f"[monitor] summary: {monitor.summary()}")
+    print(f"[compile] {compile_counts()}")
     return params, losses
 
 

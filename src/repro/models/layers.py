@@ -181,9 +181,10 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, kind: str,
     kv_len = None
     new_cache = None
     if cache is not None and "pages_k" in cache and cross_kv is None:
-        o, new_cache = _paged_attend(q, k, v, cache, page_state, cfg,
-                                     kind, positions, backend, conv_w,
-                                     head_top_k=head_top_k)
+        with jax.named_scope("attend"):
+            o, new_cache = _paged_attend(q, k, v, cache, page_state, cfg,
+                                         kind, positions, backend, conv_w,
+                                         head_top_k=head_top_k)
         o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
         out = o @ wcast(p["wo"], dt)
         return out, new_cache
@@ -228,13 +229,14 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, kind: str,
     elif conv_w is not None:
         k = apply_key_conv(conv_w, k)
 
-    o = attention_dispatch(a, "dense" if kind == "cross" else kind,
-                           q, k, v, key_conv_weights=None,
-                           q_positions=positions,
-                           kv_len=kv_len, backend=backend,
-                           causal=causal and cross_kv is None,
-                           centroids=(new_cache or {}).get("centroids")
-                           if kind == "moba" else None)
+    with jax.named_scope("attend"):
+        o = attention_dispatch(a, "dense" if kind == "cross" else kind,
+                               q, k, v, key_conv_weights=None,
+                               q_positions=positions,
+                               kv_len=kv_len, backend=backend,
+                               causal=causal and cross_kv is None,
+                               centroids=(new_cache or {}).get("centroids")
+                               if kind == "moba" else None)
     o = o.transpose(0, 2, 1, 3).reshape(b, n, h * dh)
     o = constrain(o, ("dp", None, "tp") if tp_enabled()
                   else ("dp", "sp", None))

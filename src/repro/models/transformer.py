@@ -73,30 +73,35 @@ def apply_block(p: dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
     attn_kind = {"shared_attn": "dense", "decoder": "moba"
                  if cfg.attention.kind == "moba" else "dense"}.get(kind, kind)
     if kind == "cross":
-        h, new_cache = L.apply_attention(
-            p["attn"], L.rms_norm(x, p["norm1"], cfg.rms_norm_eps), cfg,
-            "cross", positions=positions, cross_kv=cross_kv)
+        with jax.named_scope("attn_cross"):
+            h, new_cache = L.apply_attention(
+                p["attn"], L.rms_norm(x, p["norm1"], cfg.rms_norm_eps), cfg,
+                "cross", positions=positions, cross_kv=cross_kv)
     else:
         self_cache = cache.get("self") if (kind == "decoder"
                                            and cache is not None) else cache
-        h, new_cache = L.apply_attention(
-            p["attn"], L.rms_norm(x, p["norm1"], cfg.rms_norm_eps), cfg,
-            attn_kind, positions=positions, cache=self_cache,
-            backend=backend, causal=causal, page_state=page_state,
-            head_top_k=head_top_k)
+        with jax.named_scope(f"attn_{attn_kind}"):
+            h, new_cache = L.apply_attention(
+                p["attn"], L.rms_norm(x, p["norm1"], cfg.rms_norm_eps), cfg,
+                attn_kind, positions=positions, cache=self_cache,
+                backend=backend, causal=causal, page_state=page_state,
+                head_top_k=head_top_k)
     x = x + h
     if kind == "decoder":
-        h, _ = L.apply_attention(
-            p["cross"], L.rms_norm(x, p["norm_cross"], cfg.rms_norm_eps),
-            cfg, "cross", positions=positions, cross_kv=cross_kv)
+        with jax.named_scope("attn_cross"):
+            h, _ = L.apply_attention(
+                p["cross"], L.rms_norm(x, p["norm_cross"], cfg.rms_norm_eps),
+                cfg, "cross", positions=positions, cross_kv=cross_kv)
         x = x + h
         new_cache = {"self": new_cache} if new_cache is not None else None
     if "moe" in p:
-        h, aux = MOE.apply_moe(
-            p["moe"], L.rms_norm(x, p["norm2"], cfg.rms_norm_eps), cfg)
+        with jax.named_scope("moe"):
+            h, aux = MOE.apply_moe(
+                p["moe"], L.rms_norm(x, p["norm2"], cfg.rms_norm_eps), cfg)
     elif "mlp" in p:
-        h = L.apply_mlp(p["mlp"], L.rms_norm(x, p["norm2"],
-                                             cfg.rms_norm_eps))
+        with jax.named_scope("mlp"):
+            h = L.apply_mlp(p["mlp"], L.rms_norm(x, p["norm2"],
+                                                 cfg.rms_norm_eps))
     else:
         return x, aux, new_cache
     return x + h, aux, new_cache
@@ -178,7 +183,8 @@ def lm_apply(params, tokens: jax.Array, cfg: ModelConfig, *,
     under static routing) run the static ``top_k``."""
     pattern, n_groups = _block_kinds(cfg)
     dt = jnp.dtype(cfg.dtype)
-    x = params["embed"].astype(dt)[tokens]
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]
     # Megatron-SP residual stream: batch over dp, sequence over model —
     # remat-saved layer inputs shard 16x; SPMD all-gathers around the TP
     # matmuls (sequence length is always a model-axis multiple here).
@@ -226,15 +232,16 @@ def lm_apply(params, tokens: jax.Array, cfg: ModelConfig, *,
         (x, aux), new_caches = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)),
             (params["blocks"], caches, route_map))
-    x = L.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"]).astype(dt)
-    # force (d replicated, vocab tp-sharded) before the matmul: SPMD then
-    # all-gathers the small head slice instead of all-reducing the huge
-    # (B,S,V) partial logits (measured 109 GB/device of AR without this).
-    head = constrain(head, (None, "tp"))
-    logits = x @ head
-    logits = constrain(logits, ("dp", None, "tp"))
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"]).astype(dt)
+        # force (d replicated, vocab tp-sharded) before the matmul: SPMD then
+        # all-gathers the small head slice instead of all-reducing the huge
+        # (B,S,V) partial logits (measured 109 GB/device of AR without this).
+        head = constrain(head, (None, "tp"))
+        logits = x @ head
+        logits = constrain(logits, ("dp", None, "tp"))
     return logits, aux, new_caches
 
 
@@ -254,12 +261,13 @@ def lm_loss(params, batch: dict, cfg: ModelConfig,
     # memory-frugal CE: logsumexp + target gather — never materializes an
     # fp32 (B,S,V) tensor (the convert fuses into the reduction; measured
     # 263GB -> single-digit GB per device on qwen3-0.6b train_4k).
-    lse = jax.nn.logsumexp(logits, axis=-1)                  # (B,S)
-    tgt_logit = jnp.take_along_axis(
-        logits, tgt[..., None], axis=-1)[..., 0].astype(jnp.float32)
-    ll = tgt_logit - lse.astype(jnp.float32)
-    mask = batch.get("mask", jnp.ones_like(tgt, jnp.float32))
-    loss = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
+    with jax.named_scope("loss"):
+        lse = jax.nn.logsumexp(logits, axis=-1)              # (B,S)
+        tgt_logit = jnp.take_along_axis(
+            logits, tgt[..., None], axis=-1)[..., 0].astype(jnp.float32)
+        ll = tgt_logit - lse.astype(jnp.float32)
+        mask = batch.get("mask", jnp.ones_like(tgt, jnp.float32))
+        loss = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
     return loss + aux, {"ce": loss, "aux": aux}
 
 

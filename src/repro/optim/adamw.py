@@ -5,6 +5,7 @@ the paper's recipe: β=(0.9, 0.95), wd 0.1, clip 1.0, cosine to 10% peak.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, NamedTuple, Tuple
 
 import jax
@@ -45,6 +46,7 @@ def clip_by_global_norm(grads, max_norm: float):
 _NO_DECAY = ("norm", "scale", "bias", "a_log", "dt_bias", "d_skip")
 
 
+@functools.partial(jax.named_call, name="optimizer")
 def adamw_update(params, grads, state: AdamWState, cfg: TrainConfig,
                  lr_fn=None) -> Tuple[dict, AdamWState, dict]:
     lr_fn = lr_fn or cosine_schedule(cfg)

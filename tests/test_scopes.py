@@ -1,0 +1,100 @@
+"""The names the train step's device work carries into a profiler trace.
+
+Every op of a compiled program keeps the ``jax.named_scope`` path it was
+traced under in its ``op_name`` metadata, which a profile's HLO carries
+beside the device ops, so device time can be attributed by it.  These
+tests compile the train step of the moba-340m smoke configuration on
+the flash backend (interpret mode on the CPU) and read the paths back,
+in the forward pass, its rematerialisation and the backward pass.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro import configs
+from repro.configs.base import TrainConfig
+from repro.launch import steps as S
+from repro.models import transformer as T
+from repro.optim import adamw
+
+# the scopes this model's step runs under (PERF.md lists the whole
+# vocabulary, with attn_dense, attn_cross and moe for other layouts)
+LAYER_SCOPES = ("embed", "attn_swa", "attn_moba", "attend", "mlp",
+                "lm_head", "loss", "optimizer")
+MOBA_FWD = ("moba_centroids", "moba_topk", "moba_layout", "moba_fwd",
+            "moba_merge")
+MOBA_BWD = ("moba_bwd_prep", "moba_bwd", "moba_bwd_reduce")
+
+
+def segments(op_name: str) -> list[str]:
+    """The path's segments with JAX's transformation wrappers taken off:
+    ``transpose(jvp(attn_moba))`` reads ``attn_moba``."""
+    return [re.sub(r"^(?:[\w.]+\()*(.*?)\)*$", r"\1", s)
+            for s in op_name.split("/")]
+
+
+@pytest.fixture(scope="module")
+def op_names():
+    cfg = configs.get_smoke_config("moba-340m")
+    tcfg = TrainConfig(global_batch_size=1, seq_len=64, total_steps=10,
+                       warmup_steps=2)
+    step = jax.jit(S.make_train_step(cfg, tcfg, backend="flash",
+                                     remat=True))
+    params = jax.eval_shape(lambda: T.init_lm(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(adamw.adamw_init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, 65), jnp.int32)}
+    text = step.lower(params, state, batch).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+def test_segments_take_off_the_wrappers():
+    assert segments("jit(f)/transpose(jvp(attn_moba))/attend/kbwd/cos") \
+        == ["f", "attn_moba", "attend", "kbwd", "cos"]
+    assert segments("a/checkpoint/rematted_computation/attn_swa/dot") \
+        == ["a", "checkpoint", "rematted_computation", "attn_swa", "dot"]
+
+
+def test_every_scope_is_in_the_compiled_step(op_names):
+    seen = {s for n in op_names for s in segments(n)}
+    missing = set(LAYER_SCOPES + MOBA_FWD + MOBA_BWD) - seen
+    assert not missing
+
+
+def test_moba_stages_run_inside_the_moba_attention_core(op_names):
+    for n in op_names:
+        segs = segments(n)
+        stages = set(segs) & set(MOBA_FWD + MOBA_BWD)
+        if stages:
+            assert "attn_moba" in segs and "attend" in segs, n
+            assert segs.index("attn_moba") < segs.index("attend"), n
+
+
+@pytest.mark.parametrize("phase", ["forward", "remat", "backward"])
+def test_moba_stages_in_each_phase(op_names, phase):
+    """The forward stages appear in the forward pass and again where the
+    backward pass recomputes it; the backward stages under the
+    transpose."""
+    def phase_of(n):
+        if "rematted_computation" in n:
+            return "remat"
+        return "backward" if "transpose(" in n else "forward"
+
+    seen = {s for n in op_names if phase_of(n) == phase
+            for s in segments(n)}
+    want = MOBA_BWD if phase == "backward" else MOBA_FWD
+    assert set(want) <= seen
+
+
+def test_kernels_carry_their_names(op_names):
+    """A Pallas call's own ``name`` is a segment under its stage."""
+    pairs = {("moba_centroids", "centroids"), ("moba_topk", "flash_topk"),
+             ("moba_fwd", "moba_fwd"), ("moba_bwd", "moba_bwd")}
+    found = set()
+    for n in op_names:
+        segs = segments(n)
+        for a, b in zip(segs, segs[1:]):
+            if (a, b) in pairs:
+                found.add((a, b))
+    assert found == pairs

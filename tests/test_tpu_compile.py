@@ -12,6 +12,8 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -59,6 +61,7 @@ def _compile(fn, shapes, sharding):
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 def test_centroids_lower(one_chip):
@@ -94,6 +97,37 @@ def test_flash_moba_grad_lowers(one_chip, grid):
         return out.astype(jnp.float32).sum()
 
     _compile(jax.grad(loss, argnums=(0, 1, 2)), QKV, one_chip)
+
+
+# the MoBA stage scope (``kernels/ops.py``) each kernel runs under
+KERNEL_OF_STAGE = {"moba_centroids": "centroids", "moba_topk": "flash_topk",
+                   "moba_fwd": "moba_fwd", "moba_bwd": "moba_bwd"}
+
+
+@pytest.mark.parametrize("grid", ["grouped", "flat"])
+def test_flash_moba_grad_kernels_carry_their_scopes(one_chip, grid):
+    """Every ``tpu_custom_call`` of the lowered grad is named by its
+    Pallas call, which heads its op's name in a device trace, and has
+    its stage scope in its ``op_name``."""
+    def loss(q, k, v):
+        out = ops.flash_moba(q, k, v, MOBA, grid=grid, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), QKV, one_chip)
+    seen = set()
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = re.match(r"\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)? = ", line)[1]
+        path = re.search(r'op_name="([^"]*)"', line)[1]
+        # a segment may be wrapped by a transformation: jvp(moba_fwd)
+        segs = [re.sub(r"^(?:[\w.]+\()*(.*?)\)*$", r"\1", s)
+                for s in path.split("/")]
+        stages = [s for s in segs if s in KERNEL_OF_STAGE]
+        assert stages, path
+        assert name == KERNEL_OF_STAGE[stages[-1]], (name, stages)
+        seen.add(name)
+    assert seen == set(KERNEL_OF_STAGE.values())
 
 
 @pytest.mark.parametrize("grid", ["grouped", "flat"])
