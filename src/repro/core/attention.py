@@ -67,6 +67,52 @@ def dense_attention(q, k, v, causal: bool = True,
     return _apply_and_project(p, v, q.dtype)
 
 
+def banded_window_attention(q, k, v, window: int,
+                            scale: Optional[float] = None) -> jax.Array:
+    """Causal sliding-window self-attention over whole sequences at
+    positions ``0..n-1``: the function of ``dense_attention(q, k, v,
+    causal=True, window=window)``, scored on a band only.
+
+    The queries are cut into chunks of ``C`` tokens, the window rounded up
+    to a multiple of 128 (and at most the sequence so rounded).  Chunk i
+    scores the keys of chunks i-1 and i, which hold the whole window of
+    each of its queries because ``C >= window``: ``n·2C`` scores where the
+    dense path makes ``n²``.  Every score left out is one the dense path
+    masks to ``NEG_INF``, whose softmax weight is exactly 0, and the math
+    is the dense path's (float32 operands, the same scale and mask), so
+    only the work shrinks.  Differentiated by ordinary autodiff.
+    """
+    with jax.named_scope("swa_band"):
+        b, h, n, d = q.shape
+        hkv = k.shape[1]
+        if scale is None:
+            scale = 1.0 / (d ** 0.5)
+        c = -(-min(window, n) // 128) * 128
+        nc = -(-n // c)
+
+        def chunked(x):                           # (B,H,n,D) -> (B,H,nc,C,D)
+            x = jnp.pad(x.astype(jnp.float32),
+                        ((0, 0), (0, 0), (0, nc * c - n), (0, 0)))
+            return x.reshape(b, x.shape[1], nc, c, x.shape[-1])
+
+        def band(x):              # (B,Hkv,nc,C,D) -> (B,Hkv,nc,2C,D): i-1, i
+            prev = jnp.pad(x[:, :, :-1],
+                           ((0, 0), (0, 0), (1, 0), (0, 0), (0, 0)))
+            return jnp.concatenate([prev, x], axis=3)
+
+        qc = chunked(q).reshape(b, hkv, h // hkv, nc, c, d)
+        kb, vb = band(chunked(k)), band(chunked(v))
+        s = jnp.einsum("bhgcqd,bhckd->bhgcqk", qc, kb) * scale
+        qpos = jnp.arange(nc * c).reshape(nc, c)
+        kpos = qpos[:, :1] - c + jnp.arange(2 * c)            # (nc, 2C)
+        qp, kp = qpos[:, :, None], kpos[:, None, :]
+        mask = (kp >= 0) & (kp <= qp) & (qp - kp < window)   # (nc, C, 2C)
+        s = jnp.where(mask, s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bhgcqk,bhckd->bhgcqd", p, vb)
+        return o.reshape(b, h, nc * c, -1)[:, :, :n].astype(q.dtype)
+
+
 def attention_dispatch(cfg: AttentionConfig, kind: str, q, k, v,
                        key_conv_weights=None,
                        q_positions=None, kv_len=None,

@@ -134,15 +134,23 @@ class AttentionBackend:
                 q_positions=None, kv_len=None, causal: bool = True,
                 **opts) -> jax.Array:
         """Multi-token attention: training, prefill, or cached prefill
-        (``kv_len`` marks the valid prefix of a dense cache)."""
+        (``kv_len`` marks the valid prefix of a dense cache).
+
+        Causal SWA over whole sequences at positions ``0..n-1`` (no
+        cache, no explicit positions: training) scores only the band
+        around the diagonal; every other call takes the dense path."""
         if kind == "moba":
             return self.moba_prefill(cfg, q, k, v, q_positions=q_positions,
                                      **opts)
-        from repro.core.attention import dense_attention
+        from repro.core.attention import (banded_window_attention,
+                                          dense_attention)
+        window = self._window(cfg, kind)
+        if (window and causal and kv_len is None and q_positions is None
+                and q.shape[2] == k.shape[2]):
+            return banded_window_attention(q, k, v, window, scale=cfg.scale)
         return dense_attention(q, k, v, causal=causal,
                                q_positions=q_positions, kv_len=kv_len,
-                               window=self._window(cfg, kind),
-                               scale=cfg.scale)
+                               window=window, scale=cfg.scale)
 
     def decode(self, cfg: AttentionConfig, kind: str, q, k, v, kv_len, *,
                centroids=None, q_positions=None, **opts) -> jax.Array:

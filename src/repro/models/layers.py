@@ -169,9 +169,15 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, kind: str,
         q = rms_norm(q, p["q_norm_scale"], cfg.rms_norm_eps)
         k = rms_norm(k, p["k_norm_scale"], cfg.rms_norm_eps)
 
+    q_positions = positions
     if positions is None:
         positions = (jnp.arange(n) if cache is None
                      else cache["len"] + jnp.arange(n))
+        # a fresh SWA sequence passes no positions on: the backend then
+        # knows they are 0..n-1 and scores only the band (a traced arange
+        # cannot be inspected); RoPE still reads ``positions``
+        if kind != "swa" or cache is not None:
+            q_positions = positions
     if _uses_rope(cfg, kind):
         q = apply_rope(q, positions, a.rope_theta)
         if cross_kv is None:
@@ -232,7 +238,7 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, kind: str,
     with jax.named_scope("attend"):
         o = attention_dispatch(a, "dense" if kind == "cross" else kind,
                                q, k, v, key_conv_weights=None,
-                               q_positions=positions,
+                               q_positions=q_positions,
                                kv_len=kv_len, backend=backend,
                                causal=causal and cross_kv is None,
                                centroids=(new_cache or {}).get("centroids")

@@ -35,18 +35,33 @@ def segments(op_name: str) -> list[str]:
             for s in op_name.split("/")]
 
 
+# four SWA chunks of 128 (window 32 rounded up), and no other width of
+# the smoke model equals it, so an (n, n) score would show by its shape
+SEQ_LEN = 512
+
+
 @pytest.fixture(scope="module")
-def op_names():
+def hlo_text():
     cfg = configs.get_smoke_config("moba-340m")
-    tcfg = TrainConfig(global_batch_size=1, seq_len=64, total_steps=10,
+    tcfg = TrainConfig(global_batch_size=1, seq_len=SEQ_LEN, total_steps=10,
                        warmup_steps=2)
     step = jax.jit(S.make_train_step(cfg, tcfg, backend="flash",
                                      remat=True))
     params = jax.eval_shape(lambda: T.init_lm(jax.random.PRNGKey(0), cfg))
     state = jax.eval_shape(adamw.adamw_init, params)
-    batch = {"tokens": jax.ShapeDtypeStruct((1, 65), jnp.int32)}
-    text = step.lower(params, state, batch).compile().as_text()
-    return re.findall(r'op_name="([^"]*)"', text)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, SEQ_LEN + 1), jnp.int32)}
+    return step.lower(params, state, batch).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def op_names(hlo_text):
+    return re.findall(r'op_name="([^"]*)"', hlo_text)
+
+
+def phase_of(op_name: str) -> str:
+    if "rematted_computation" in op_name:
+        return "remat"
+    return "backward" if "transpose(" in op_name else "forward"
 
 
 def test_segments_take_off_the_wrappers():
@@ -76,11 +91,6 @@ def test_moba_stages_in_each_phase(op_names, phase):
     """The forward stages appear in the forward pass and again where the
     backward pass recomputes it; the backward stages under the
     transpose."""
-    def phase_of(n):
-        if "rematted_computation" in n:
-            return "remat"
-        return "backward" if "transpose(" in n else "forward"
-
     seen = {s for n in op_names if phase_of(n) == phase
             for s in segments(n)}
     want = MOBA_BWD if phase == "backward" else MOBA_FWD
@@ -98,3 +108,18 @@ def test_kernels_carry_their_names(op_names):
             if (a, b) in pairs:
                 found.add((a, b))
     assert found == pairs
+
+
+@pytest.mark.parametrize("phase", ["forward", "remat", "backward"])
+def test_swa_attention_runs_on_the_band_in_each_phase(op_names, phase):
+    assert any(phase_of(n) == phase
+               and "/attn_swa/attend/swa_band/" in "/".join(segments(n))
+               for n in op_names)
+
+
+def test_no_swa_op_holds_a_score_over_the_whole_sequence(hlo_text):
+    full = re.compile(rf"\b{SEQ_LEN},{SEQ_LEN}\]")
+    for line in hlo_text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if m and "attn_swa" in segments(m.group(1)):
+            assert not full.search(line), line
