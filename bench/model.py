@@ -1,13 +1,16 @@
-"""A configuration file of ``bench/configs`` as the program runs it, and
-the weights the benchmark makes for it.
+"""A configuration file of ``bench/configs``, the architecture module it
+names, and the weights the benchmark makes for it.
 
-The weights are made here from ``--seed`` in the program's parameter
-layout (the layout is the program's checkpoint format: stacked per
-layer-pattern slot), so the program and the plain reference are given the
-same weights without the reference taking anything the program made.
+The configuration's ``arch`` key names its module in ``bench/arch``,
+which knows the program's parameter layout (the program's checkpoint
+format).  The weights are made here from ``--seed`` in that layout, so
+the program and the plain reference are given the same weights without
+the reference taking anything the program made.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from pathlib import Path
 
@@ -15,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 BENCH = Path(__file__).resolve().parent
+ARCH = BENCH / "arch"
 
 
 def load_config(name: str) -> dict:
@@ -25,69 +29,32 @@ def load_config(name: str) -> dict:
     return c
 
 
-def head_dim(c: dict) -> int:
-    return c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"]
+def load_module(path: Path):
+    """The Python file ``path`` as a module of its own."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem}".replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
-def n_groups(c: dict) -> int:
-    pattern = c["layer_pattern"]
-    if c["num_hidden_layers"] % len(pattern):
-        raise ValueError(f"{c['name']}: {c['num_hidden_layers']} layers do "
-                         f"not divide into the pattern {pattern}")
-    return c["num_hidden_layers"] // len(pattern)
+@functools.cache
+def _arch_module(path: Path):
+    return load_module(path)
 
 
-def layer_kinds(c: dict) -> list[str]:
-    """Attention kind of every layer, in order."""
-    return list(c["layer_pattern"]) * n_groups(c)
-
-
-def program_config(c: dict):
-    """The program's ``ModelConfig`` for configuration ``c``."""
-    from repro.configs.base import AttentionConfig, MoBAConfig, ModelConfig
-    moba = MoBAConfig(block_size=c["moba_block_size"], top_k=c["moba_top_k"],
-                      key_conv_width=c["moba_key_conv_width"])
-    attn = AttentionConfig(kind="moba", moba=moba,
-                           window=c.get("sliding_window") or 256,
-                           rope_on_moba=c["rope_on_moba"],
-                           qk_norm=c["qk_norm"],
-                           rope_theta=float(c["rope_theta"]))
-    return ModelConfig(
-        name=c["name"], family="dense", num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=head_dim(c),
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        max_seq_len=c["max_position_embeddings"],
-        tie_embeddings=c["tie_word_embeddings"],
-        rms_norm_eps=c["rms_norm_eps"], attention=attn,
-        layer_pattern=tuple(c["layer_pattern"]), dtype=c["compute_dtype"],
-        param_dtype=c["param_dtype"])
-
-
-def param_specs(c: dict) -> dict:
-    """``{path: (shape, init)}`` of every weight; ``init`` is ``"one"``
-    for norm scales or the standard deviation of a normal draw."""
-    d, h, hkv, dh = (c["hidden_size"], c["num_attention_heads"],
-                     c["num_key_value_heads"], head_dim(c))
-    ff, v, g = c["intermediate_size"], c["vocab_size"], n_groups(c)
-    specs = {"embed": ((v, d), 0.02), "final_norm": ((d,), "one")}
-    if not c["tie_word_embeddings"]:
-        specs["lm_head"] = ((d, v), d ** -0.5)
-    for i, _ in enumerate(c["layer_pattern"]):
-        s = f"blocks/slot_{i}/"
-        specs[s + "norm1"] = ((g, d), "one")
-        specs[s + "norm2"] = ((g, d), "one")
-        specs[s + "attn/wq"] = ((g, d, h * dh), d ** -0.5)
-        specs[s + "attn/wk"] = ((g, d, hkv * dh), d ** -0.5)
-        specs[s + "attn/wv"] = ((g, d, hkv * dh), d ** -0.5)
-        specs[s + "attn/wo"] = ((g, h * dh, d), (h * dh) ** -0.5)
-        if c["qk_norm"]:
-            specs[s + "attn/q_norm_scale"] = ((g, dh), "one")
-            specs[s + "attn/k_norm_scale"] = ((g, dh), "one")
-        specs[s + "mlp/w_gate"] = ((g, d, ff), d ** -0.5)
-        specs[s + "mlp/w_up"] = ((g, d, ff), d ** -0.5)
-        specs[s + "mlp/w_down"] = ((g, ff, d), ff ** -0.5)
-    return specs
+def arch(c: dict):
+    """The architecture module ``bench/arch/<arch>.py`` that configuration
+    ``c`` names: the program's config and weight layout, the plain
+    reference's layers, and the work of a token (``bench/arch/
+    moba_transformer.py`` is the first)."""
+    path = ARCH / f"{c['arch']}.py"
+    if not path.is_file():
+        shown = (path.relative_to(BENCH.parent)
+                 if path.is_relative_to(BENCH.parent) else path)
+        raise FileNotFoundError(f"{c['name']}: no architecture module "
+                                f"{c['arch']!r}; add {shown}")
+    return _arch_module(path)
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -110,7 +77,7 @@ def _nest(flat: dict) -> dict:
 def make_weights(c: dict, seed: int) -> dict:
     """Every weight of ``c`` in float32, drawn on the device in one jitted
     call from ``seed`` (the same seed gives the same weights)."""
-    specs = param_specs(c)
+    specs = arch(c).param_specs(c)
 
     def draw(key):
         flat = {}
@@ -130,7 +97,7 @@ def check_layout(c: dict, params) -> None:
     the benchmark makes (names, shapes or dtypes)."""
     from repro.models import transformer as T
     want = jax.eval_shape(lambda: T.init_lm(jax.random.PRNGKey(0),
-                                            program_config(c)))
+                                            arch(c).program_config(c)))
     got = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                        params)
     if jax.tree.structure(want) != jax.tree.structure(got) or any(

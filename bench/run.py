@@ -5,11 +5,12 @@
 
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``.  Everything
 is found by name: the configuration in ``bench/configs/<config>.json``,
-the traffic mix in ``bench/traffic/<traffic>.json`` (its ``kind`` names
-the driver, ``bench/<kind>.py``), the limits of the correctness check in
-``bench/limits/<workload>.json``, each per-layer metric's reader in
-``bench/metrics/<metric>.py`` and each kernel's work in
-``bench/work/<kernel>.py``.
+its architecture in ``bench/arch/<arch>.py`` (the configuration's
+``arch`` key), the traffic mix in ``bench/traffic/<traffic>.json`` (its
+``kind`` names the driver, ``bench/<kind>.py``), the limits of the
+correctness check in ``bench/limits/<workload>.json``, each per-layer
+metric's reader in ``bench/metrics/<metric>.py`` and each kernel's work
+in ``bench/work/<kernel>.py``.
 
 The run refuses to start without a TPU, or with fewer chips than the cell
 asks for.  Set-up (weights from ``--seed``, compilation, warm-up, the
@@ -29,7 +30,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import importlib  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import shutil  # noqa: E402
@@ -46,15 +46,10 @@ for p in (str(ROOT / "src"), str(ROOT)):
     if p not in sys.path:
         sys.path.insert(0, p)
 
+from bench import model  # noqa: E402
+from bench.model import load_module  # noqa: E402
+
 TRACE_DIR = ROOT / ".bench_traces"
-
-
-def load_module(path: Path):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{path.parent.name}_{path.stem}".replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def load_json(path: Path) -> dict:
@@ -70,20 +65,24 @@ def applies(metric: dict, cell: str, reported: set) -> bool:
 
 def resolve(workload: str) -> dict:
     """Everything one cell needs, found by the names in ``BENCHMARK.json``."""
-    from bench.model import load_config
     spec = load_json(ROOT / "BENCHMARK.json")
     entries = {w["name"]: w for w in spec["workloads"]}
     if workload not in entries:
         raise SystemExit(f"unknown workload {workload!r}; the benchmark has "
                          f"{sorted(entries)}")
     w = entries[workload]
+    config = model.load_config(w["config"])
+    try:
+        model.arch(config)
+    except FileNotFoundError as e:
+        raise SystemExit(str(e)) from None
     e2e = [m for m in spec["end_to_end"] if applies(m, workload, set())]
     reported = {m["name"] for m in e2e}
     per_layer = [m for m in spec["per_layer"]
                  if applies(m, workload, reported)]
     return {
         "workload": w,
-        "config": load_config(w["config"]),
+        "config": config,
         "traffic": load_json(BENCH / "traffic" / f"{w['traffic']}.json"),
         "limits": load_json(BENCH / "limits" / f"{workload}.json"),
         "end_to_end": e2e,

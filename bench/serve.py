@@ -95,7 +95,7 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, t_start: float
 
     c, tr = cell["config"], cell["traffic"]
     e = tr["engine"]
-    cfg = model.program_config(c)
+    cfg = model.arch(c).program_config(c)
     params = model.make_weights(c, seed)
     model.check_layout(c, params)
     engine = Engine(cfg, params, EngineConfig(

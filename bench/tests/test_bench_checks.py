@@ -12,8 +12,9 @@ import pytest
 
 from bench import run, serve, train
 
-TINY = {"name": "tiny", "hidden_size": 64, "head_dim": 16,
-        "intermediate_size": 128, "max_position_embeddings": 512,
+TINY = {"name": "tiny", "arch": "moba_transformer", "hidden_size": 64,
+        "head_dim": 16, "intermediate_size": 128,
+        "max_position_embeddings": 512,
         "num_attention_heads": 4, "num_hidden_layers": 2,
         "num_key_value_heads": 2, "rms_norm_eps": 1e-6, "rope_theta": 1e4,
         "sliding_window": 32, "vocab_size": 256, "moba_block_size": 16,

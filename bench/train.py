@@ -154,7 +154,7 @@ def run(cell: dict, seed: int, seconds: float, trace_dir, t_start: float
     opt = tr["adamw"]
     if opt["warmup_steps"] <= CHECK_STEPS:
         raise ValueError("the check's steps must lie inside the warm-up")
-    cfg = model.program_config(c)
+    cfg = model.arch(c).program_config(c)
     tcfg = TrainConfig(global_batch_size=tr["batch"], seq_len=tr["seq"],
                        learning_rate=opt["learning_rate"],
                        warmup_steps=opt["warmup_steps"],
