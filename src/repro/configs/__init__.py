@@ -6,9 +6,9 @@ import importlib
 from repro.configs.base import (  # noqa: F401  (public re-exports)
     ASSIGNED_SHAPES, AttentionConfig, Config, MeshConfig, MoBAConfig,
     ModelConfig, MoEConfig, ServeConfig, ShardingConfig, SSMConfig,
-    TrainConfig, with_moba)
+    TrainConfig, YarnRope, with_moba)
 
-# assigned architectures (10) + the paper's own models (2)
+# assigned architectures (11) + the paper's own models (2)
 ARCHS = {
     "qwen3-0.6b": "qwen3_0_6b",
     "qwen3-14b": "qwen3_14b",
@@ -20,6 +20,7 @@ ARCHS = {
     "seamless-m4t-medium": "seamless_m4t_medium",
     "llama-3.2-vision-90b": "llama_3_2_vision_90b",
     "zamba2-1.2b": "zamba2_1_2b",
+    "mellum2-12b-a2.5b": "mellum2_12b_a2_5b",
     "moba-340m": "moba_340m",
     "moba-1b": "moba_1b",
 }

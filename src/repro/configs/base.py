@@ -34,6 +34,22 @@ class MoBAConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN scaling of a rotary embedding (Peng et al. 2023), with the
+    parameters of HF transformers' ``rope_type="yarn"``: inverse
+    frequencies blended between the interpolated (``/factor``) and the
+    original ones by a linear ramp between the ``beta_fast`` and
+    ``beta_slow`` correction dims of ``original_max_position_embeddings``,
+    and cos/sin scaled by ``attention_factor`` (None: 0.1·ln(factor)+1)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionConfig:
     """Per-layer attention behaviour.
 
@@ -48,17 +64,30 @@ class AttentionConfig:
     rope_on_moba: bool = True  # paper's hybrid uses NoPE on MoBA layers
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    # YaRN on the moba layers' RoPE (Mellum2's full-attention layers);
+    # the other kinds keep plain RoPE at ``rope_theta``
+    moba_yarn: Optional[YarnRope] = None
     # softmax scale override; None -> 1/sqrt(head_dim)
     scale: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
+    """``num_experts`` is the router's width; the layer holds experts
+    ``0 .. experts_held-1`` of them (0: all), as one chip of an
+    expert-parallel deployment holds its share, and computes only their
+    part of the output."""
+
     num_experts: int = 0
     top_k: int = 2
     num_shared_experts: int = 0
     expert_d_ff: int = 0  # per-expert hidden size
     router_aux_coef: float = 0.001
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)

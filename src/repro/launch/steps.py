@@ -83,6 +83,9 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                                                    opt_state, tcfg, lr_fn)
         out = {"loss": loss}
         out.update(om)
+        if "expert_load" in metrics:
+            # (expert layers, held): pairs routed to each held expert
+            out["expert_load"] = metrics["expert_load"]
         return params, opt_state, out
 
     return train_step

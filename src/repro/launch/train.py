@@ -108,13 +108,18 @@ def train(arch: str, steps: int = 50, batch: int = 8, seq: int = 512,
         losses.append(loss)
         monitor.beat(step)
         if on_step:
-            on_step(step, {k: float(x) for k, x in metrics.items()})
+            on_step(step, {k: float(x) for k, x in metrics.items()
+                           if k != "expert_load"})
         if step % log_every == 0 or step == steps - 1:
             dt = time.time() - t0
+            load = ""
+            if "expert_load" in metrics:
+                el = np.asarray(metrics["expert_load"])
+                load = f"expert_load max {el.max()} mean {el.mean():.1f} "
             print(f"step {step:5d} loss {loss:8.4f} "
                   f"lr {float(metrics.get('lr', 0)):.2e} "
                   f"gnorm {float(metrics.get('grad_norm', 0)):7.3f} "
-                  f"[{dt:6.1f}s]")
+                  f"{load}[{dt:6.1f}s]")
         if mgr and ((step + 1) % save_interval == 0 or step == end - 1):
             mgr.save(step + 1,
                      {"params": params, "mu": opt_state.mu,

@@ -26,7 +26,8 @@ def param_count(cfg: ModelConfig) -> int:
 
 
 def active_param_count(cfg: ModelConfig) -> int:
-    """MoE-aware: only top_k + shared experts count as active."""
+    """MoE-aware: of the experts a layer holds, a token runs through
+    top_k · held / num_experts on average; shared experts are active."""
     total = param_count(cfg)
     if cfg.family != "moe" or not cfg.moe.num_experts:
         return total
@@ -35,8 +36,8 @@ def active_param_count(cfg: ModelConfig) -> int:
     pattern, n_groups = T._block_kinds(cfg)
     n_moe_layers = sum(1 for k in pattern if k not in ("cross", "ssm")
                       ) * n_groups
-    inactive = n_moe_layers * (m.num_experts - m.top_k) * per_expert
-    return total - inactive
+    active = m.top_k * m.held / m.num_experts
+    return total - round(n_moe_layers * (m.held - active) * per_expert)
 
 
 def input_specs(cfg: ModelConfig, shape: str,
@@ -75,7 +76,7 @@ def make_forward(cfg: ModelConfig, backend: str = "sparse"):
         if cfg.num_encoder_layers and src_embeds is not None:
             ck = T.apply_encoder(params, src_embeds, cfg,
                                  backend=backend)
-        logits, aux, _ = T.lm_apply(params, tokens, cfg,
-                                    backend=backend, cross_kv=ck)
+        logits, _, _ = T.lm_apply(params, tokens, cfg,
+                                  backend=backend, cross_kv=ck)
         return logits
     return forward

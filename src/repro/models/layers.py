@@ -11,12 +11,13 @@ attention therefore always see the same convolved keys (paper App. B).
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnRope
 from repro.core.attention import attention_dispatch
 from repro.core.key_conv import (apply_key_conv, apply_key_conv_decode,
                                  init_key_conv, key_conv_state_init)
@@ -48,21 +49,51 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
 
 
 # -------------------------------------------------------------------- rope
-def rope_freqs(head_dim: int, theta: float = 10000.0) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                            / head_dim))
+def rope_freqs(head_dim: int, theta: float = 10000.0,
+               yarn: Optional[YarnRope] = None) -> jax.Array:
+    """Inverse frequencies of the (head_dim/2) rotated pairs; with
+    ``yarn``, HF transformers' ``_compute_yarn_parameters``: the dims that
+    turn fewer than ``beta_slow`` times over the original context are
+    interpolated (frequency / factor), those that turn more than
+    ``beta_fast`` times kept, and a linear ramp blends the dims between."""
+    pos = jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim
+    freqs = 1.0 / (theta ** pos)
+    if yarn is None:
+        return freqs
+
+    def correction_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / ((high - low) or 0.001), 0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
+
+
+def yarn_attention_factor(yarn: YarnRope) -> float:
+    if yarn.attention_factor is not None:
+        return yarn.attention_factor
+    return 0.1 * math.log(yarn.factor) + 1.0 if yarn.factor > 1 else 1.0
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 10000.0) -> jax.Array:
+               theta: float = 10000.0,
+               yarn: Optional[YarnRope] = None) -> jax.Array:
     """x: (B, H, N, d); positions: (N,) shared or (B, N) per-sequence
-    (ragged serving batches where each row sits at a different offset)."""
+    (ragged serving batches where each row sits at a different offset).
+    ``yarn`` scales the frequencies and cos/sin (:func:`rope_freqs`)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)
+    freqs = rope_freqs(d, theta, yarn)
     positions = jnp.asarray(positions)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., N, d/2)
     ang = ang[None, None] if positions.ndim == 1 else ang[:, None]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if yarn is not None:
+        mscale = yarn_attention_factor(yarn)
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.astype(x.dtype)
@@ -179,9 +210,10 @@ def apply_attention(p: dict, x: jax.Array, cfg: ModelConfig, kind: str,
         if kind != "swa" or cache is not None:
             q_positions = positions
     if _uses_rope(cfg, kind):
-        q = apply_rope(q, positions, a.rope_theta)
+        yarn = a.moba_yarn if kind == "moba" else None
+        q = apply_rope(q, positions, a.rope_theta, yarn)
         if cross_kv is None:
-            k = apply_rope(k, positions, a.rope_theta)
+            k = apply_rope(k, positions, a.rope_theta, yarn)
 
     conv_w = p.get("key_conv") if kind == "moba" else None
     kv_len = None

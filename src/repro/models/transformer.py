@@ -60,15 +60,18 @@ def apply_block(p: dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
                 positions=None, cache=None, backend="reference",
                 cross_kv=None, causal=True, page_state=None,
                 head_top_k=None):
-    """Pre-LN block. Returns (x, aux_loss, new_cache).
+    """Pre-LN block. Returns (x, aux_loss, new_cache, expert_load), the
+    last the (held,) pairs an expert layer routed to each held expert
+    (None for other blocks).
 
     ``head_top_k``: optional (H,) int32 per-head routing budgets for this
     layer's MoBA attention (adaptive routing profile, DESIGN.md §8)."""
     aux = jnp.zeros((), jnp.float32)
+    load = None
     if kind == "ssm":
         h, new_cache = M.apply_mamba2(p["mamba"], L.rms_norm(
             x, p["norm1"], cfg.rms_norm_eps), cfg, cache)
-        return x + h, aux, new_cache
+        return x + h, aux, new_cache, load
 
     attn_kind = {"shared_attn": "dense", "decoder": "moba"
                  if cfg.attention.kind == "moba" else "dense"}.get(kind, kind)
@@ -96,15 +99,15 @@ def apply_block(p: dict, x: jax.Array, cfg: ModelConfig, kind: str, *,
         new_cache = {"self": new_cache} if new_cache is not None else None
     if "moe" in p:
         with jax.named_scope("moe"):
-            h, aux = MOE.apply_moe(
+            h, aux, load = MOE.apply_moe(
                 p["moe"], L.rms_norm(x, p["norm2"], cfg.rms_norm_eps), cfg)
     elif "mlp" in p:
         with jax.named_scope("mlp"):
             h = L.apply_mlp(p["mlp"], L.rms_norm(x, p["norm2"],
                                                  cfg.rms_norm_eps))
     else:
-        return x, aux, new_cache
-    return x + h, aux, new_cache
+        return x, aux, new_cache, load
+    return x + h, aux, new_cache, load
 
 
 # ------------------------------------------------------------------- model
@@ -149,8 +152,8 @@ def apply_encoder(params, src_embeds: jax.Array, cfg: ModelConfig,
     x = src_embeds.astype(cfg.dtype)
 
     def body(x, p):
-        x, _, _ = apply_block(p, x, cfg, enc_kind, causal=False,
-                              backend=backend)
+        x, _, _, _ = apply_block(p, x, cfg, enc_kind, causal=False,
+                                 backend=backend)
         return x, None
 
     if unroll:
@@ -169,7 +172,11 @@ def lm_apply(params, tokens: jax.Array, cfg: ModelConfig, *,
              remat: bool = False, unroll: bool = False,
              page_state: Optional[dict] = None,
              route_map: Optional[dict] = None):
-    """tokens (B, S) -> (logits (B, S, V), aux, new_caches).
+    """tokens (B, S) -> (logits (B, S, V), stats, new_caches).
+
+    ``stats``: ``aux_loss``, the expert layers' auxiliary loss summed
+    (0 without experts), and for expert models ``expert_load``, the
+    (layers, held) pairs each expert layer routed to each expert it holds.
 
     ``unroll=True`` replaces the layer-group scan with a python loop —
     needed by the dry-run because XLA cost_analysis counts while-loop
@@ -194,24 +201,26 @@ def lm_apply(params, tokens: jax.Array, cfg: ModelConfig, *,
     def group_body(carry, xs):
         x, aux = carry
         gparams, gcaches, groute = xs
-        new_gcaches = {}
+        new_gcaches, loads = {}, {}
         for i, kind in enumerate(pattern):
             p_i = (params["shared"] if kind == "shared_attn"
                    else gparams[f"slot_{i}"])
             cache_i = None if gcaches is None else gcaches.get(f"slot_{i}")
             rt_i = None if groute is None else groute.get(f"slot_{i}")
-            x, a, nc = apply_block(p_i, x, cfg, kind,
-                                   positions=positions, cache=cache_i,
-                                   backend=backend,
-                                   page_state=page_state,
-                                   head_top_k=rt_i,
-                                   cross_kv=cross_kv
-                                   if kind in ("cross", "decoder")
-                                   else None)
+            x, a, nc, ld = apply_block(p_i, x, cfg, kind,
+                                       positions=positions, cache=cache_i,
+                                       backend=backend,
+                                       page_state=page_state,
+                                       head_top_k=rt_i,
+                                       cross_kv=cross_kv
+                                       if kind in ("cross", "decoder")
+                                       else None)
             if nc is not None:
                 new_gcaches[f"slot_{i}"] = nc
+            if ld is not None:
+                loads[f"slot_{i}"] = ld
             aux = aux + a
-        return (x, aux), (new_gcaches or None)
+        return (x, aux), (new_gcaches or None, loads or None)
 
     body = jax.checkpoint(group_body) if remat else group_body
     if unroll:
@@ -226,12 +235,20 @@ def lm_apply(params, tokens: jax.Array, cfg: ModelConfig, *,
             carry, y = body(carry, (gp, gc, gr))
             ys.append(y)
         (x, aux) = carry
-        new_caches = (None if ys[0] is None else
-                      jax.tree.map(lambda *a: jnp.stack(a), *ys))
+        new_caches, loads = (
+            None if ys[0][j] is None else
+            jax.tree.map(lambda *a: jnp.stack(a), *[y[j] for y in ys])
+            for j in (0, 1))
     else:
-        (x, aux), new_caches = jax.lax.scan(
+        (x, aux), (new_caches, loads) = jax.lax.scan(
             body, (x, jnp.zeros((), jnp.float32)),
             (params["blocks"], caches, route_map))
+    stats = {"aux_loss": aux}
+    if loads is not None:
+        # (groups, held) per slot -> (layers, held) in layer order
+        stats["expert_load"] = jnp.stack(
+            [loads[k] for k in sorted(loads, key=lambda k: int(k[5:]))],
+            axis=1).reshape(-1, cfg.moe.held)
     with jax.named_scope("lm_head"):
         x = L.rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
         head = (params["embed"].T if cfg.tie_embeddings
@@ -242,22 +259,24 @@ def lm_apply(params, tokens: jax.Array, cfg: ModelConfig, *,
         head = constrain(head, (None, "tp"))
         logits = x @ head
         logits = constrain(logits, ("dp", None, "tp"))
-    return logits, aux, new_caches
+    return logits, stats, new_caches
 
 
 def lm_loss(params, batch: dict, cfg: ModelConfig,
             backend: str = "reference", remat: bool = False,
             unroll: bool = False):
-    """batch: {'tokens': (B, S+1) int32} → mean next-token CE + MoE aux."""
+    """batch: {'tokens': (B, S+1) int32} → (mean next-token CE + MoE aux,
+    metrics: ``ce``, ``aux`` and for expert models ``expert_load``)."""
     tokens = batch["tokens"]
     inp, tgt = tokens[:, :-1], tokens[:, 1:]
     cross_kv = batch.get("cross_kv")
     if cfg.num_encoder_layers and "src_embeds" in batch:
         cross_kv = apply_encoder(params, batch["src_embeds"], cfg,
                                  backend=backend, unroll=unroll)
-    logits, aux, _ = lm_apply(params, inp, cfg, backend=backend,
-                              cross_kv=cross_kv, remat=remat,
-                              unroll=unroll)
+    logits, stats, _ = lm_apply(params, inp, cfg, backend=backend,
+                                cross_kv=cross_kv, remat=remat,
+                                unroll=unroll)
+    aux = stats["aux_loss"]
     # memory-frugal CE: logsumexp + target gather — never materializes an
     # fp32 (B,S,V) tensor (the convert fuses into the reduction; measured
     # 263GB -> single-digit GB per device on qwen3-0.6b train_4k).
@@ -268,7 +287,10 @@ def lm_loss(params, batch: dict, cfg: ModelConfig,
         ll = tgt_logit - lse.astype(jnp.float32)
         mask = batch.get("mask", jnp.ones_like(tgt, jnp.float32))
         loss = -(ll * mask).sum() / jnp.maximum(mask.sum(), 1.0)
-    return loss + aux, {"ce": loss, "aux": aux}
+    metrics = {"ce": loss, "aux": aux}
+    if "expert_load" in stats:
+        metrics["expert_load"] = stats["expert_load"]
+    return loss + aux, metrics
 
 
 # -------------------------------------------------------------------- cache
@@ -339,7 +361,7 @@ def prefill(params, tokens: jax.Array, cfg: ModelConfig, caches,
     prefill passes per-row (B, S) offsets instead."""
     if positions is None:
         positions = jnp.arange(tokens.shape[1])
-    logits, aux, new_caches = lm_apply(
+    logits, _, new_caches = lm_apply(
         params, tokens, cfg, caches=caches, backend=backend,
         cross_kv=cross_kv, unroll=unroll, page_state=page_state,
         positions=positions, route_map=route_map)

@@ -11,6 +11,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import configs
@@ -123,3 +124,55 @@ def test_no_swa_op_holds_a_score_over_the_whole_sequence(hlo_text):
         m = re.search(r'op_name="([^"]*)"', line)
         if m and "attn_swa" in segments(m.group(1)):
             assert not full.search(line), line
+
+
+# ------------------------------------------------------- the expert layer
+MOE_SCOPES = ("route", "dispatch", "expert_gmm", "combine")
+MOE_SEQ = 64
+
+
+def mellum_step(cfg):
+    tcfg = TrainConfig(global_batch_size=1, seq_len=MOE_SEQ, total_steps=10,
+                       warmup_steps=2)
+    return jax.jit(S.make_train_step(cfg, tcfg, backend="flash",
+                                     remat=True))
+
+
+@pytest.fixture(scope="module")
+def moe_op_names():
+    cfg = configs.get_smoke_config("mellum2-12b-a2.5b")
+    params = jax.eval_shape(lambda: T.init_lm(jax.random.PRNGKey(0), cfg))
+    state = jax.eval_shape(adamw.adamw_init, params)
+    batch = {"tokens": jax.ShapeDtypeStruct((1, MOE_SEQ + 1), jnp.int32)}
+    text = mellum_step(cfg).lower(params, state, batch).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', text)
+
+
+@pytest.mark.parametrize("name", MOE_SCOPES + ("kernel expert_gmm",))
+def test_expert_layer_names_in_the_compiled_step(moe_op_names, name):
+    """Each stage of the expert layer sits under ``moe``, forward and
+    backward; the grouped matmuls' Pallas calls carry ``expert_gmm``
+    under their stage."""
+    pair = (("expert_gmm", "expert_gmm") if name.startswith("kernel")
+            else ("moe", name))
+    for phase in ("forward", "backward"):
+        assert any(phase_of(n) == phase
+                   and "/".join(pair) in "/".join(segments(n))
+                   for n in moe_op_names), (name, phase)
+
+
+def test_the_step_counts_each_held_experts_pairs():
+    """Under a fixed routing (a zero router ties every expert, and the top
+    2 are experts 0 and 1, the two held), the step's ``expert_load``
+    gives each expert layer's held experts every token."""
+    cfg = configs.get_smoke_config("mellum2-12b-a2.5b")
+    params = T.init_lm(jax.random.PRNGKey(0), cfg)
+    for slot in params["blocks"].values():
+        slot["moe"]["router"] = jnp.zeros_like(slot["moe"]["router"])
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1),
+                                          (1, MOE_SEQ + 1), 0,
+                                          cfg.vocab_size)}
+    _, _, out = mellum_step(cfg)(params, adamw.adamw_init(params), batch)
+    load = np.asarray(out["expert_load"])
+    assert load.shape == (cfg.num_layers, cfg.moe.held)
+    np.testing.assert_array_equal(load, MOE_SEQ)

@@ -12,7 +12,10 @@ The topology is described inside a fixture, never while a module is
 imported: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
+import os
 import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -179,3 +182,86 @@ def test_paged_decode_lowers(one_chip, grid, kv_dtype):
     if quant:
         shapes += [((PAGES, HKV), jnp.float32)] * 2
     _compile(fn, shapes, one_chip)
+
+
+# Mellum2-12B-A2.5B: the expert layer one chip of eight holds (8 of 64
+# experts of width 896, top 8, hidden 2304) at 1x4096 tokens, and the
+# MoBA kernels at 32 q / 4 kv heads of 128 (GQA group 8)
+MELLUM = dict(d=2304, f=896, experts=64, held=8, top_k=8, tokens=4096)
+
+
+@pytest.fixture(scope="module")
+def expert_layer_text(one_chip):
+    """The compiled gradient of the held-experts layer, its Pallas calls
+    lowered for the chip (the layer picks interpret mode off a TPU)."""
+    from repro.configs.base import ModelConfig, MoEConfig
+    from repro.models import moe as MOE
+    m = MELLUM
+    cfg = ModelConfig(family="moe", d_model=m["d"], dtype="bfloat16",
+                      moe=MoEConfig(num_experts=m["experts"],
+                                    top_k=m["top_k"], expert_d_ff=m["f"],
+                                    experts_held=m["held"]))
+    params = jax.eval_shape(lambda: MOE.init_moe(jax.random.PRNGKey(0),
+                                                 cfg))
+
+    def loss(p, x):
+        out, aux, _ = MOE.apply_moe(p, x, cfg)
+        return out.astype(jnp.float32).sum() + aux
+
+    shard = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,  # noqa: E731
+                                           sharding=one_chip)
+    old = os.environ.get("REPRO_PALLAS_INTERPRET")
+    os.environ["REPRO_PALLAS_INTERPRET"] = "0"
+    try:
+        x = jax.ShapeDtypeStruct((1, m["tokens"], m["d"]), jnp.bfloat16,
+                                 sharding=one_chip)
+        return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.tree.map(shard, params), x).compile().as_text()
+    finally:
+        if old is None:
+            del os.environ["REPRO_PALLAS_INTERPRET"]
+        else:
+            os.environ["REPRO_PALLAS_INTERPRET"] = old
+
+
+def _custom_calls(text):
+    return [re.sub(r'\s*(?:ROOT )?', "", ln, count=1).split(", metadata=")[0]
+            for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
+
+
+def test_expert_gmm_lowers_and_carries_its_name(expert_layer_text):
+    """Forward, input-gradient and weight-gradient grouped matmuls of the
+    three expert matrices: nine calls, every one named ``expert_gmm``."""
+    names = [re.match(r"%([\w-]+?)(?:\.\d+)? = ", c)[1]
+             for c in _custom_calls(expert_layer_text)]
+    assert names == ["expert_gmm"] * 9
+
+
+def test_only_the_expert_gmm_pattern_finds_its_calls(expert_layer_text):
+    """The benchmark finds a kernel's device ops by a pattern on the op's
+    HLO text (``MATCH`` in ``bench/work/<kernel>.py``): no other kernel's
+    pattern takes an ``expert_gmm`` call."""
+    import importlib
+    root = Path(__file__).resolve().parents[1]
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    patterns = {p.stem: importlib.import_module(f"bench.work.{p.stem}").MATCH
+                for p in sorted((root / "bench" / "work").glob("*.py"))
+                if "MATCH = " in p.read_text()}
+    assert "expert_gmm" in patterns
+    for call in _custom_calls(expert_layer_text):
+        hits = {k for k, pat in patterns.items() if re.search(pat, call)}
+        assert hits == {"expert_gmm"}, (hits, call[:200])
+
+
+def test_flash_moba_grad_lowers_at_gqa_group_8(one_chip):
+    h, hkv, d, n = 32, 4, 128, 4096
+
+    def loss(q, k, v):
+        out = ops.flash_moba(q, k, v, MOBA, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)),
+             [((1, h, n, d), jnp.bfloat16), ((1, hkv, n, d), jnp.bfloat16),
+              ((1, hkv, n, d), jnp.bfloat16)], one_chip)
